@@ -10,8 +10,10 @@ repo root — the perf-trajectory file CI diffs per PR:
   * ``scan``     the shard_map backend's compiled per-device trial loop
                  (needs K >= D agent devices; runs at the largest K)
 
-Device count cannot change after jax initialises, so each K runs in a
-subprocess with ``XLA_FLAGS=--xla_force_host_platform_device_count=K``.
+Device count cannot change after jax initialises, so on the CPU each K
+runs in a subprocess with ``XLA_FLAGS=--xla_force_host_platform_device_count=K``.
+On an accelerator the parent process holds the chips, and a child could not
+reach them, so the suite measures the real devices in-process instead.
 Timings exclude compilation (one warm call first) and measure the compiled
 program itself — built by the SAME `api.runner` program builders `batch_fit`
 executes (`_local_batch_program` / `_shard_map_batch_program`), so the timed
@@ -47,23 +49,12 @@ _TRIAL_COUNTS = (4, 8) if _SMOKE else (8, 32, 128)
 _REPS = 1 if _SMOKE else 2
 
 
-def _worker(cfg: dict) -> None:
-    """Runs in the subprocess (device count fixed by XLA_FLAGS): time every
-    path available at this device count, print one JSON dict to stdout."""
-    import contextlib
-
+def _measure(cfg: dict) -> list:
+    """Time every path available at this process's device count."""
     import jax
 
     from repro import api
-    from repro.analysis import recompile
     from repro.api import runner as runner_mod
-
-    # when the parent is audited (DESIGN.md §9.3), count this worker's
-    # compiles too and report them on stdout — the parent absorbs them, so
-    # the bench_batch audit covers the forked per-device-count runs
-    audit = (recompile.count_compilations()
-             if os.environ.get("REPRO_RECOMPILE_AUDIT")
-             else contextlib.nullcontext(None))
 
     k = len(jax.devices())
     n_sweeps, n_train = cfg["n_sweeps"], cfg["n_train"]
@@ -103,22 +94,44 @@ def _worker(cfg: dict) -> None:
                 "trials_per_sec": round(n_trials / dt, 2),
                 "ms_per_batch": round(dt * 1e3, 1)}
 
+    paths = ["vmap"] if k == 1 else ["sharded"]
+    if k >= cfg["n_agents"]:
+        paths.append("scan")
+    results = [measure(p, cfg["n_trials"]) for p in paths]
+    if cfg.get("trial_scaling"):
+        # batch-size curve for the parallel paths; the scan path is
+        # sequential by construction (one trial at a time on the agent
+        # mesh), so its throughput does not scale with batch size — skip
+        for n in cfg["trial_counts"]:
+            for p in paths:
+                if n != cfg["n_trials"] and p != "scan":
+                    results.append(measure(p, n))
+    return results
+
+
+def _worker(cfg: dict) -> None:
+    """Runs in the subprocess (device count fixed by XLA_FLAGS): print the
+    measured rows as one JSON line on stdout."""
+    import contextlib
+
+    from repro.analysis import recompile
+
+    # when the parent is audited (DESIGN.md §9.3), count this worker's
+    # compiles too and report them on stdout — the parent absorbs them, so
+    # the bench_batch audit covers the forked per-device-count runs
+    audit = (recompile.count_compilations()
+             if os.environ.get("REPRO_RECOMPILE_AUDIT")
+             else contextlib.nullcontext(None))
     with audit as compile_log:
-        paths = ["vmap"] if k == 1 else ["sharded"]
-        if k >= cfg["n_agents"]:
-            paths.append("scan")
-        results = [measure(p, cfg["n_trials"]) for p in paths]
-        if cfg.get("trial_scaling"):
-            # batch-size curve for the parallel paths; the scan path is
-            # sequential by construction (one trial at a time on the agent
-            # mesh), so its throughput does not scale with batch size — skip
-            for n in cfg["trial_counts"]:
-                for p in paths:
-                    if n != cfg["n_trials"] and p != "scan":
-                        results.append(measure(p, n))
+        results = _measure(cfg)
     print("BENCH_JSON:" + json.dumps(results))
     if compile_log is not None:
         print("AUDIT_COUNTS:" + json.dumps(compile_log.counts))
+
+
+def _config(trial_scaling: bool) -> dict:
+    return dict(_SCENARIO, reps=_REPS, n_agents=_N_AGENTS,
+                trial_scaling=trial_scaling, trial_counts=list(_TRIAL_COUNTS))
 
 
 def _spawn(devices: int, trial_scaling: bool) -> list:
@@ -127,8 +140,7 @@ def _spawn(devices: int, trial_scaling: bool) -> list:
     root = os.path.join(os.path.dirname(__file__), "..")
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(root, "src"), root, env.get("PYTHONPATH", "")])
-    cfg = dict(_SCENARIO, reps=_REPS, n_agents=_N_AGENTS,
-               trial_scaling=trial_scaling, trial_counts=list(_TRIAL_COUNTS))
+    cfg = _config(trial_scaling)
     code = ("import json,sys; from benchmarks.batch_bench import _worker; "
             "_worker(json.loads(sys.argv[1]))")
     out = subprocess.run([sys.executable, "-c", code, json.dumps(cfg)],
@@ -151,10 +163,19 @@ def _spawn(devices: int, trial_scaling: bool) -> list:
 
 
 def run():
+    import jax
+
     results = []
-    max_k = _DEVICE_COUNTS[-1]
-    for k in _DEVICE_COUNTS:
-        rows = _spawn(k, trial_scaling=(k in (1, max_k)))
+    on_cpu = jax.default_backend() == "cpu"
+    device_counts = _DEVICE_COUNTS if on_cpu else (len(jax.devices()),)
+    max_k = device_counts[-1]
+    for k in device_counts:
+        if on_cpu:
+            rows = _spawn(k, trial_scaling=(k in (1, max_k)))
+        else:
+            # one process per chip: this process now holds the accelerator,
+            # so the real devices are measured here and no child is started
+            rows = _measure(_config(trial_scaling=True))
         results.extend(rows)
         for r in rows:
             us = 1e6 / r["trials_per_sec"]
@@ -177,7 +198,8 @@ def run():
         "unit": "trials_per_sec",
         "smoke": _SMOKE,
         "host_cpu_count": os.cpu_count(),
-        "device_counts": list(_DEVICE_COUNTS),
+        "backend": jax.default_backend(),
+        "device_counts": list(device_counts),
         "results": results,
         f"sharded_dev{max_k}_speedup_over_vmap":
             None if speedup is None else round(speedup, 2),

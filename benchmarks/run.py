@@ -20,6 +20,7 @@ import sys
 import traceback
 
 from repro.analysis import recompile
+from repro.launch.compile_cache import use_compile_cache
 
 from benchmarks import (batch_bench, comm_cost, faults_bench,
                         fig1_overtraining, fig3_divergence, fig5_upper_bound,
@@ -49,24 +50,26 @@ SUITES = {
 }
 
 
-def _env_row() -> str:
+def _env_row(cache: str) -> str:
     """One self-describing row: which parts of tools/bench_env.sh are active."""
     alloc = "tcmalloc" if "tcmalloc" in os.environ.get("LD_PRELOAD", "") \
         else "glibc"
-    cache = "on" if os.environ.get("JAX_COMPILATION_CACHE_DIR") else "off"
     xla = os.environ.get("XLA_FLAGS", "")
     return f"bench_env,0,alloc={alloc};jax_cache={cache};xla_flags={xla or '-'}"
 
 
 def main() -> int:
     which = sys.argv[1:] or list(SUITES)
+    cache = use_compile_cache(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache"))
     # recompilation audit (DESIGN.md §9.3): active only when
     # REPRO_RECOMPILE_AUDIT names a JSON path — the audit is written at exit,
     # tagged per suite selection so tools/recompile_budget.json can hold one
     # entry per benchmark entry point (bench_batch, bench_kernels, ...)
     recompile.install_from_env("bench_" + "_".join(sorted(which)))
     print("name,us_per_call,derived")
-    print(_env_row(), flush=True)
+    print(_env_row(cache), flush=True)
     failed = 0
     for name in which:
         try:

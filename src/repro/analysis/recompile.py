@@ -41,7 +41,9 @@ import jax
 __all__ = ["CompilationLog", "count_compilations", "install_from_env",
            "absorb_counts", "load_budget", "check_budget", "write_audit"]
 
-_COMPILE_RE = re.compile(r"^Compiling ([\w<>\-.]+)")
+# jax logs "Compiling jit(<name>) with global shapes ..."; the group is the
+# function name inside the transform wrapper
+_COMPILE_RE = re.compile(r"^Compiling (?:\w+\()?([\w<>\-.]+)")
 # the channel that emits one record per real XLA compile under
 # jax_log_compiles (cache hits are silent)
 _PXLA_LOGGER = "jax._src.interpreters.pxla"

@@ -30,7 +30,17 @@ import threading
 from typing import Any, Callable, Iterator, Tuple, TypeVar
 
 import jax.numpy as jnp
+from jax._src.interpreters import batching
+from jax._src.lax import lax as _lax_internal
 from jax.experimental import checkify
+
+# checkify keeps a while-loop's cond checks alive with a `dce_sink` in the
+# body.  jax 0.9's vmap rule for that primitive hands its operand back as an
+# output although it has none, so vmap-of-checkify-of-while (the batch trial
+# rail, api.runner) fails with "foreach() argument 2 is longer than argument
+# 1".  The primitive has no outputs, batched or not.
+batching.primitive_batchers[_lax_internal.dce_sink_p] = (
+    lambda args, dims, **params: ([], []))
 
 __all__ = ["CHECK_MODES", "checks_enabled", "sanitize_scope", "checked",
            "check_finite", "check_nonzero", "check_in_bounds",

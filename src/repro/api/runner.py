@@ -288,8 +288,8 @@ def _local_batch_program(spec: ExperimentSpec, n_trials: int):
     def shard(t):
         return jax.vmap(run_fn)(t)
 
-    fn = distributed._shmap(shard, mesh,
-                            in_specs=P("trials"), out_specs=P("trials"))
+    fn = jax.shard_map(shard, mesh=mesh, in_specs=P("trials"),
+                       out_specs=P("trials"), check_vma=False)
     return fn, trials
 
 

@@ -36,7 +36,8 @@ def gram(r: jnp.ndarray, use_kernel: bool = False) -> jnp.ndarray:
         from repro.kernels.gram import ops as gram_ops
 
         return (gram_ops.gram(r, use_pallas=True) / r.shape[1]).astype(r.dtype)
-    return (r @ r.T) / r.shape[1]
+    # full f32 precision: the TPU default rounds through bf16 passes
+    return jnp.matmul(r, r.T, precision=jax.lax.Precision.HIGHEST) / r.shape[1]
 
 
 def residual_covariance(residuals: jnp.ndarray, use_kernel: bool = False) -> jnp.ndarray:

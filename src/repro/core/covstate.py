@@ -53,6 +53,10 @@ __all__ = ["CovState", "build", "refresh", "row_product", "row_update_vector",
            "eta_probe", "s_probe", "robust_eta_probe", "apply_inverse_update",
            "apply_row_update", "replace_row", "replace_col"]
 
+# contractions over the instances at full f32 precision: the TPU default
+# rounds f32 products through bf16 passes (DESIGN.md §10.2)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 class CovState(NamedTuple):
     """Immutable covariance solve state (a pytree — jit/shard_map friendly)."""
@@ -74,7 +78,7 @@ def row_product(vec: jnp.ndarray, r_sub: jnp.ndarray,
         from repro.kernels.gram import ops as gram_ops
 
         return gram_ops.row_gram(vec, r_sub, use_pallas=True).astype(r_sub.dtype)
-    return r_sub @ vec
+    return jnp.matmul(r_sub, vec, precision=_HIGHEST)
 
 
 def _with_solve(r_sub: jnp.ndarray, a0: jnp.ndarray) -> CovState:
@@ -115,7 +119,8 @@ def row_update_vector(state: CovState, i, delta_sub: jnp.ndarray,
     m = state.r_sub.shape[1]
     w = row_product(delta_sub, state.r_sub, use_kernel=use_kernel) / m
     if ddiag is None:
-        return w.at[i].add(jnp.vdot(delta_sub, delta_sub) / (2.0 * m))
+        return w.at[i].add(jnp.vdot(delta_sub, delta_sub,
+                                  precision=_HIGHEST) / (2.0 * m))
     return w.at[i].set(0.5 * ddiag)
 
 

@@ -54,19 +54,6 @@ __all__ = ["make_agent_mesh", "distributed_sweep", "run_distributed",
            "run_refit_scan_distributed"]
 
 
-def _shmap(body, mesh: Mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: the top-level binding (with
-    check_vma) landed after 0.4.x; fall back to jax.experimental.shard_map
-    (check_rep) on older runtimes."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def make_agent_mesh(n_agents: int) -> Mesh:
     devs = jax.devices()
     if len(devs) < n_agents:
@@ -87,7 +74,7 @@ def _gathered_a0(f_sub_all: jnp.ndarray, y_sub: jnp.ndarray, diag_all: jnp.ndarr
     r_sub = y_sub[None, :] - f_sub_all
     if tp is not None:
         r_sub = tp.relay_rows_st(r_sub)
-    a0 = (r_sub @ r_sub.T) / r_sub.shape[1]
+    a0 = cov.gram(r_sub)
     if alpha > 1.0:
         if tp is not None:
             diag_all = tp.relay_scalars_st(diag_all)
@@ -430,8 +417,8 @@ def _sweep_shmap(mesh: Mesh, cfg: ICOAConfig, family):
     body_fn = (_sweep_body_incremental if cfg.engine in ("incremental", "fused")
                else _sweep_body)
     body = partial(body_fn, cfg, tp, family)
-    sm = _shmap(
-        body, mesh,
+    sm = jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(P("agents"), P(), P("agents"), P("agents"), P(), P(), P()),
         # the trailing P() is a tree PREFIX for the tap dict: every leaf of
         # the (possibly empty) replicated tap pytree is unsharded
@@ -601,8 +588,8 @@ def _averaging_shmap(mesh: Mesh, family):
         f = family.predict(p, xcol[0])
         return jax.tree.map(lambda t: t[None], p), f[None]
 
-    return _shmap(
-        body, mesh,
+    return jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(P("agents"), P(), P("agents")),
         out_specs=(P("agents"), P("agents")),
     )
@@ -661,8 +648,8 @@ def _refit_cycle_shmap(mesh: Mesh, family, codec=None):
 
         return jax.lax.fori_loop(0, dd, agent_update, (f_local, params_local))
 
-    return _shmap(
-        cycle, mesh,
+    return jax.shard_map(
+        cycle, mesh=mesh, check_vma=False,
         in_specs=(P("agents"), P(), P("agents"), P("agents")),
         out_specs=(P("agents"), P("agents")),
     )

@@ -71,7 +71,7 @@ def cached_row_gradient(v: jnp.ndarray, r_sub: jnp.ndarray, i,
     split), because then A0_ii does not depend on the transmitted subsample
     and the caller adds the exact-diagonal term (2/N) v_i^2 r_i separately.
     """
-    cross = v @ r_sub
+    cross = jnp.matmul(v, r_sub, precision=jax.lax.Precision.HIGHEST)
     if exclude_self:
         cross = cross - v[i] * r_sub[i]
     return (2.0 / r_sub.shape[1]) * v[i] * cross

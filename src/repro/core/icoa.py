@@ -63,6 +63,11 @@ from repro.transport import ledger as ledger_mod
 __all__ = ["ICOAConfig", "ICOAState", "init_state", "sweep", "run", "run_scan",
            "converged_record", "ensemble_predict"]
 
+# contractions over the N instances run at full f32 precision: at the
+# default a TPU rounds f32 operands through bf16 passes, and the
+# back-search's accept decisions amplify that (DESIGN.md §10.2)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 @dataclasses.dataclass(frozen=True)
 class ICOAConfig:
@@ -407,8 +412,8 @@ def _sweep_incremental(family, cfg: ICOAConfig, tp, params: Any, f: jnp.ndarray,
         # pieces — the residual delta of probing step is -step * g_unit.
         g_sub = g_unit if idx is None else g_unit[idx]
         p = covstate.row_product(g_sub, cs.r_sub, use_kernel=uk) / m
-        gg = jnp.vdot(g_sub, g_sub)
-        c1 = jnp.vdot(r_i, g_unit)              # exact-diagonal cross term
+        gg = jnp.vdot(g_sub, g_sub, precision=_HIGHEST)
+        c1 = jnp.vdot(r_i, g_unit, precision=_HIGHEST)  # exact-diag cross term
 
         def u_of(step):
             w = -step * p
@@ -457,7 +462,8 @@ def _sweep_incremental(family, cfg: ICOAConfig, tp, params: Any, f: jnp.ndarray,
         if idx is None:
             ddiag_acc = None
         else:
-            ddiag_acc = tp.relay_scalar(jnp.vdot(r_new, r_new) / n, i) - cs.a0[i, i]
+            ddiag_acc = tp.relay_scalar(
+                jnp.vdot(r_new, r_new, precision=_HIGHEST) / n, i) - cs.a0[i, i]
         u_acc = covstate.row_update_vector(cs, i, r_new_sub - cs.r_sub[i],
                                            ddiag=ddiag_acc, use_kernel=uk)
         if cfg.accept_reject:
@@ -613,9 +619,11 @@ def _sweep_fused(family, cfg: ICOAConfig, tp, params: Any, f: jnp.ndarray,
         phi_t, ginv = _poly_projector(xcols, family.degree, family.ridge)
 
         def project(i, p_old, f_hat):
-            del p_old  # closed form
-            p_new = ginv[i] @ (phi_t[i] @ f_hat)
-            return p_new, p_new @ phi_t[i]
+            del p_old  # closed form, at the family's own precision
+            p_new = jnp.matmul(
+                ginv[i], jnp.matmul(phi_t[i], f_hat, precision=_HIGHEST),
+                precision=_HIGHEST)
+            return p_new, jnp.matmul(p_new, phi_t[i], precision=_HIGHEST)
     else:
         def project(i, p_old, f_hat):
             p_new = family.fit(p_old, xcols[i], f_hat)
@@ -638,7 +646,7 @@ def _sweep_fused(family, cfg: ICOAConfig, tp, params: Any, f: jnp.ndarray,
                 g_unit = g / gnorm
                 # R @ g_unit = (2 s_i / (m gnorm)) * (A0 @ s): zero-pass probe
                 p = (2.0 * s[i] / (m * gnorm)) * (a0 @ s)
-                gg = jnp.vdot(g_unit, g_unit)
+                gg = jnp.vdot(g_unit, g_unit, precision=_HIGHEST)
                 etas = sweep_ref.probe_etas_closed(
                     m_inv, s, eta, i, steps, p,
                     jnp.zeros((), f.dtype), gg / (2.0 * m))
@@ -651,7 +659,7 @@ def _sweep_fused(family, cfg: ICOAConfig, tp, params: Any, f: jnp.ndarray,
             g_unit = g / gnorm
             g_sub = g_unit[idx]
             p = covstate.row_product(g_sub, rs, use_kernel=uk) / m
-            c1 = jnp.vdot(r_i, g_unit)          # exact-diagonal cross term
+            c1 = jnp.vdot(r_i, g_unit, precision=_HIGHEST)  # exact-diag cross
             etas = sweep_ref.probe_etas_closed(
                 m_inv, s, eta, i, steps, p.at[i].set(0.0),
                 -c1 / n, 0.5 / jnp.asarray(n, f.dtype))
@@ -678,7 +686,8 @@ def _sweep_fused(family, cfg: ICOAConfig, tp, params: Any, f: jnp.ndarray,
             diag_keep = jnp.ones((), f.dtype)
             diag_add = jnp.zeros((), f.dtype)
         else:
-            ddiag_acc = tp.relay_scalar(jnp.vdot(r_new, r_new) / n, i) - a0[i, i]
+            ddiag_acc = tp.relay_scalar(
+                jnp.vdot(r_new, r_new, precision=_HIGHEST) / n, i) - a0[i, i]
             diag_keep = jnp.zeros((), f.dtype)
             diag_add = 0.5 * ddiag_acc
         threshold = eta0 if cfg.accept_reject else neg_inf

@@ -10,7 +10,8 @@ vectors of N instances each, N >> D. TPU mapping:
     the sequential innermost grid dim), written out on the last step.
 
 VMEM budget at the default BN=2048, Dp=128: tile 128*2048*4 = 1 MiB + scratch
-64 KiB — comfortably inside the ~16 MiB/core VMEM.
+64 KiB — comfortably inside the ~16 MiB/core VMEM.  Dp=1024 at BN=2048 does
+not fit a v5e's VMEM; the main path stays at D<=512 until D is tiled.
 
 The `*_batched` variants prepend a batch grid axis (grid = (B, NK), batch
 outermost, N-blocks innermost-sequential) so a whole Monte-Carlo trial batch
@@ -32,6 +33,19 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["gram_pallas", "gram_pallas_batched", "row_gram_pallas",
            "row_gram_pallas_batched"]
 
+# full-precision f32 dots split their operands in VMEM; at Dp=512 and
+# block_n=2048 that passes the default 16 MiB scoped limit (a v5e core has
+# 128 MiB of VMEM)
+_VMEM = pltpu.CompilerParams(vmem_limit_bytes=48 * 2**20)
+
+
+def _dot(x, y):
+    """x @ y^T at full f32 precision (the TPU default rounds f32 operands to
+    bf16, which the covariance inverse downstream amplifies)."""
+    return jax.lax.dot_general(x, y, (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
 
 def _gram_kernel(r_ref, out_ref, acc_ref, *, nk: int):
     k = pl.program_id(0)
@@ -41,10 +55,7 @@ def _gram_kernel(r_ref, out_ref, acc_ref, *, nk: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     blk = r_ref[...].astype(jnp.float32)        # (Dp, BN)
-    acc_ref[...] += jax.lax.dot_general(
-        blk, blk, (((1,), (1,)), ((), ())),      # R_blk @ R_blk^T
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _dot(blk, blk)               # R_blk @ R_blk^T
 
     @pl.when(k == nk - 1)
     def _flush():
@@ -63,6 +74,7 @@ def gram_pallas(r: jnp.ndarray, *, block_n: int = 2048, interpret: bool = True) 
         out_specs=pl.BlockSpec((dp, dp), lambda k: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((dp, dp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((dp, dp), jnp.float32)],
+        compiler_params=_VMEM,
         interpret=interpret,
     )(r)
 
@@ -75,10 +87,7 @@ def _gram_batch_kernel(r_ref, out_ref, acc_ref, *, nk: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     blk = r_ref[0].astype(jnp.float32)          # (Dp, BN)
-    acc_ref[...] += jax.lax.dot_general(
-        blk, blk, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _dot(blk, blk)
 
     @pl.when(k == nk - 1)
     def _flush():
@@ -103,6 +112,7 @@ def gram_pallas_batched(r: jnp.ndarray, *, block_n: int = 2048,
         out_specs=pl.BlockSpec((1, dp, dp), lambda i, k: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, dp, dp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((dp, dp), jnp.float32)],
+        compiler_params=_VMEM,
         interpret=interpret,
     )(r)
 
@@ -116,10 +126,7 @@ def _row_gram_kernel(r_ref, v_ref, out_ref, acc_ref, *, nk: int):
 
     blk = r_ref[...].astype(jnp.float32)         # (Dp, BN)
     vec = v_ref[...].astype(jnp.float32)         # (8, BN); row 0 is the payload
-    acc_ref[...] += jax.lax.dot_general(
-        blk, vec, (((1,), (1,)), ((), ())),      # R_blk @ v_blk^T -> (Dp, 8)
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _dot(blk, vec)               # R_blk @ v_blk^T -> (Dp, 8)
 
     @pl.when(k == nk - 1)
     def _flush():
@@ -149,6 +156,7 @@ def row_gram_pallas(r: jnp.ndarray, v: jnp.ndarray, *, block_n: int = 2048,
         out_specs=pl.BlockSpec((dp, 8), lambda k: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((dp, 8), jnp.float32),
         scratch_shapes=[pltpu.VMEM((dp, 8), jnp.float32)],
+        compiler_params=_VMEM,
         interpret=interpret,
     )(r, v)
 
@@ -162,10 +170,7 @@ def _row_gram_batch_kernel(r_ref, v_ref, out_ref, acc_ref, *, nk: int):
 
     blk = r_ref[0].astype(jnp.float32)           # (Dp, BN)
     vec = v_ref[0].astype(jnp.float32)           # (8, BN); row 0 is the payload
-    acc_ref[...] += jax.lax.dot_general(
-        blk, vec, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _dot(blk, vec)
 
     @pl.when(k == nk - 1)
     def _flush():
@@ -190,5 +195,6 @@ def row_gram_pallas_batched(r: jnp.ndarray, v: jnp.ndarray, *,
         out_specs=pl.BlockSpec((1, dp, 8), lambda i, k: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, dp, 8), jnp.float32),
         scratch_shapes=[pltpu.VMEM((dp, 8), jnp.float32)],
+        compiler_params=_VMEM,
         interpret=interpret,
     )(r, v)

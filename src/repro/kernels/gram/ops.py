@@ -4,9 +4,7 @@ TPU is the target; on CPU we validate through the interpreter (exercised in
 tests) but default to the ref oracle for speed inside ICOA itself.  The
 compiled-vs-interpreter choice defaults to `interpret=None` = auto-select
 from the JAX backend via kernels.runtime.resolve_interpret (compiled Mosaic
-on TPU, interpreter elsewhere; REPRO_KERNEL_INTERPRET overrides process-wide)
-— previously these ops hardcoded interpret=True, which silently ran the
-Python interpreter on real TPUs.
+on TPU, interpreter elsewhere).
 
 Batching: `pallas_call` has no built-in vmap rule, so the Pallas paths are
 wrapped in `jax.custom_batching.custom_vmap` — `jax.vmap(gram)` (the Monte-

@@ -1,48 +1,22 @@
-"""Kernel runtime knobs shared by every Pallas op wrapper.
+"""Compiled Mosaic or the Pallas interpreter: the one rule every op wrapper uses.
 
-The single policy question every op wrapper used to hardcode — "compiled
-Mosaic or the Python interpreter?" — lives here instead (DESIGN.md §7/§10):
-
-  * explicit ``interpret=`` from the caller always wins;
-  * otherwise the ``REPRO_KERNEL_INTERPRET`` env knob decides: ``0``/``1``
-    force one mode for every kernel in the process, and the default ``auto``
-    compiles on TPU backends and interprets everywhere else.
-
-``auto`` is what fixes the old footgun: ops defaulted to ``interpret=True``,
-so ``use_kernel=True`` on a real TPU silently ran the Python interpreter
-path.  The resolution is process-global state (backend + env), not per-call,
-so resolved values are safe to use as jit static arguments / lru_cache keys.
+An explicit ``interpret=`` from the caller wins.  Otherwise kernels compile
+iff the active JAX backend is TPU and run in the interpreter everywhere else,
+so ``use_kernel=True`` on a TPU always runs the compiled kernel.  The answer
+depends on process-global state only (the backend), so it is safe as a jit
+static argument or an lru_cache key.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
 
 __all__ = ["resolve_interpret"]
 
-_ENV_KNOB = "REPRO_KERNEL_INTERPRET"
-_FALSY = ("0", "false", "off", "compiled")
-_TRUTHY = ("1", "true", "on", "interpret")
-
 
 def resolve_interpret(explicit: Optional[bool] = None) -> bool:
-    """Resolve the interpret-mode tri-state to a concrete bool.
-
-    ``explicit`` (a caller-supplied ``interpret=`` argument) short-circuits;
-    ``None`` defers to ``REPRO_KERNEL_INTERPRET`` (``auto`` | ``0`` | ``1``),
-    where ``auto`` means: compiled Mosaic iff the active JAX backend is TPU.
-    """
+    """``explicit`` if given, else True unless the default backend is TPU."""
     if explicit is not None:
         return bool(explicit)
-    knob = os.environ.get(_ENV_KNOB, "auto").strip().lower()
-    if knob in _FALSY:
-        return False
-    if knob in _TRUTHY:
-        return True
-    if knob != "auto":
-        raise ValueError(
-            f"{_ENV_KNOB}={knob!r}: expected 'auto', '0'/'false'/'off', "
-            "or '1'/'true'/'on'")
     return jax.default_backend() != "tpu"

@@ -31,7 +31,9 @@ as an (8, 128) parameter plate read back via iota masks.  The zero padding
 is load-bearing: it makes full-array reductions equal payload reductions.
 
 VMEM at BN=2048, Dp=128: R tile 1 MiB + m_inv 64 KiB + packs/accumulators
-~12 KiB — the D=100/N=2000 benchmark case is a single resident tile.
+~12 KiB — the D=100/N=2000 benchmark case is a single resident tile.  Both
+kernels compile for a TPU v5e up to Dp=512 at BN=2048; at Dp=1024 they run
+out of VMEM, so D above 512 needs tiling over D.
 
 The `*_batched` variants prepend a batch grid axis (batch outermost,
 N-blocks innermost-sequential, accumulators re-initialised per element) and
@@ -54,9 +56,24 @@ __all__ = ["probe_sweep_pallas", "probe_sweep_pallas_batched",
 
 _F32 = jnp.float32
 
+# full-precision f32 dots split their operands in VMEM; at Dp=512 and
+# block_n=2048 that passes the default 16 MiB scoped limit (a v5e core has
+# 128 MiB of VMEM)
+_VMEM = pltpu.CompilerParams(vmem_limit_bytes=48 * 2**20)
+
+
+def _dot(x, y, contract):
+    """f32 product at full f32 precision.  At the default precision a TPU
+    rounds f32 operands to bf16, which moves the back-search's accept
+    decisions away from the f32 reference."""
+    return jax.lax.dot_general(x, y, (contract, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=_F32)
+
 
 def _iota2(shape, dim):
-    return jax.lax.broadcasted_iota(_F32, shape, dim)
+    # Mosaic only builds integer iotas; the f32 cast is exact for any index.
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim).astype(_F32)
 
 
 def _plate_scalar(plate, j: int):
@@ -84,8 +101,7 @@ def _probe_finalize(minv, s_col, pars, steps, acc_p, acc_gg):
     gnorm = jnp.sqrt(gg_cross) * jnp.abs(scale) + 1e-30
     p_col = acc_p * (scale / (m * gnorm))            # (Dp, 8): R @ g_unit / m
 
-    q_col = jax.lax.dot_general(minv, p_col, (((1,), (0,)), ((), ())),
-                                preferred_element_type=_F32)
+    q_col = _dot(minv, p_col, ((1,), (0,)))
     a = jnp.sum(p_col * q_col)                       # <p, q>: pad cols are zero
     b = _col0_entry(q_col, i_f)
     dmask = (_iota2(minv.shape, 0) == i_f) & (_iota2(minv.shape, 1) == i_f)
@@ -118,11 +134,9 @@ def _probe_kernel(r_ref, minv_ref, s_ref, pars_ref, steps_ref,
 
     blk = r_ref[...].astype(_F32)                    # (Dp, BN)
     s_col = s_ref[...].astype(_F32)                  # (Dp, 8)
-    cross_blk = jax.lax.dot_general(                 # (8, BN); row 0 = s @ R_blk
-        s_col, blk, (((0,), (0,)), ((), ())), preferred_element_type=_F32)
+    cross_blk = _dot(s_col, blk, ((0,), (0,)))       # (8, BN); row 0 = s @ R_blk
     cross_ref[...] = cross_blk
-    acc_p[...] += jax.lax.dot_general(               # (Dp, 8) += R_blk @ cross^T
-        blk, cross_blk, (((1,), (1,)), ((), ())), preferred_element_type=_F32)
+    acc_p[...] += _dot(blk, cross_blk, ((1,), (1,)))  # (Dp, 8) += R_blk @ cross^T
     acc_gg[...] += jnp.sum(cross_blk * cross_blk)    # broadcast: every entry
 
     @pl.when(k == nk - 1)
@@ -165,6 +179,7 @@ def probe_sweep_pallas(r: jnp.ndarray, m_inv: jnp.ndarray, s: jnp.ndarray,
                    jax.ShapeDtypeStruct((8, 128), _F32)],
         scratch_shapes=[pltpu.VMEM((dp, 8), _F32),
                         pltpu.VMEM((8, 128), _F32)],
+        compiler_params=_VMEM,
         interpret=interpret,
     )(r, m_inv, s, pars, steps)
 
@@ -181,11 +196,9 @@ def _probe_batch_kernel(r_ref, minv_ref, s_ref, pars_ref, steps_ref,
 
     blk = r_ref[0].astype(_F32)
     s_col = s_ref[0].astype(_F32)
-    cross_blk = jax.lax.dot_general(
-        s_col, blk, (((0,), (0,)), ((), ())), preferred_element_type=_F32)
+    cross_blk = _dot(s_col, blk, ((0,), (0,)))
     cross_ref[0] = cross_blk
-    acc_p[...] += jax.lax.dot_general(
-        blk, cross_blk, (((1,), (1,)), ((), ())), preferred_element_type=_F32)
+    acc_p[...] += _dot(blk, cross_blk, ((1,), (1,)))
     acc_gg[...] += jnp.sum(cross_blk * cross_blk)
 
     @pl.when(k == nk - 1)
@@ -223,6 +236,7 @@ def probe_sweep_pallas_batched(r, m_inv, s, pars, steps, *,
                    jax.ShapeDtypeStruct((b, 8, 128), _F32)],
         scratch_shapes=[pltpu.VMEM((dp, 8), _F32),
                         pltpu.VMEM((8, 128), _F32)],
+        compiler_params=_VMEM,
         interpret=interpret,
     )(r, m_inv, s, pars, steps)
 
@@ -246,10 +260,8 @@ def _commit_finalize(minv, s_col, pars, acc_w, acc_dd):
     u = jnp.where(cellmask, diag_keep * (w_i + dd_auto) + diag_add, w)
 
     e_col = jnp.where(cellmask, 1.0, 0.0)            # (Dp, 8): e_i in column 0
-    z1 = jax.lax.dot_general(minv, e_col, (((1,), (0,)), ((), ())),
-                             preferred_element_type=_F32)
-    z2 = jax.lax.dot_general(minv, u, (((1,), (0,)), ((), ())),
-                             preferred_element_type=_F32)
+    z1 = _dot(minv, e_col, ((1,), (0,)))
+    z2 = _dot(minv, u, ((1,), (0,)))
     dmask = (_iota2(minv.shape, 0) == i_f) & (_iota2(minv.shape, 1) == i_f)
     k11 = jnp.sum(jnp.where(dmask, minv, 0.0))
     k12 = 1.0 + _col0_entry(z2, i_f)
@@ -262,8 +274,7 @@ def _commit_finalize(minv, s_col, pars, acc_w, acc_dd):
     acc = jnp.where((obj_post > threshold) & (can_tx > 0.5), 1.0, 0.0)
 
     def outer(x, y):                                 # (Dp,8)x(Dp,8) -> (Dp,Dp)
-        return jax.lax.dot_general(x, y, (((1,), (1,)), ((), ())),
-                                   preferred_element_type=_F32)
+        return _dot(x, y, ((1,), (1,)))
 
     corr = (k22 * outer(z1, z1) - k12 * (outer(z1, z2) + outer(z2, z1))
             + k11 * outer(z2, z2)) / det
@@ -288,8 +299,7 @@ def _commit_kernel(r_ref, delta_ref, minv_ref, s_ref, pars_ref,
 
     blk = r_ref[...].astype(_F32)                    # (Dp, BN)
     dblk = delta_ref[...].astype(_F32)               # (8, BN); row 0 payload
-    acc_w[...] += jax.lax.dot_general(               # (Dp, 8) += R_blk @ d^T
-        blk, dblk, (((1,), (1,)), ((), ())), preferred_element_type=_F32)
+    acc_w[...] += _dot(blk, dblk, ((1,), (1,)))      # (Dp, 8) += R_blk @ d^T
     acc_dd[...] += jnp.sum(dblk * dblk)
 
     @pl.when(k == nk - 1)
@@ -335,6 +345,7 @@ def commit_sweep_pallas(r: jnp.ndarray, delta: jnp.ndarray,
                    jax.ShapeDtypeStruct((8, 128), _F32)],
         scratch_shapes=[pltpu.VMEM((dp, 8), _F32),
                         pltpu.VMEM((8, 128), _F32)],
+        compiler_params=_VMEM,
         interpret=interpret,
     )(r, delta, m_inv, s, pars)
 
@@ -351,8 +362,7 @@ def _commit_batch_kernel(r_ref, delta_ref, minv_ref, s_ref, pars_ref,
 
     blk = r_ref[0].astype(_F32)
     dblk = delta_ref[0].astype(_F32)
-    acc_w[...] += jax.lax.dot_general(
-        blk, dblk, (((1,), (1,)), ((), ())), preferred_element_type=_F32)
+    acc_w[...] += _dot(blk, dblk, ((1,), (1,)))
     acc_dd[...] += jnp.sum(dblk * dblk)
 
     @pl.when(k == nk - 1)
@@ -391,5 +401,6 @@ def commit_sweep_pallas_batched(r, delta, m_inv, s, pars, *,
                    jax.ShapeDtypeStruct((b, 8, 128), _F32)],
         scratch_shapes=[pltpu.VMEM((dp, 8), _F32),
                         pltpu.VMEM((8, 128), _F32)],
+        compiler_params=_VMEM,
         interpret=interpret,
     )(r, delta, m_inv, s, pars)

@@ -5,8 +5,7 @@ batching, fallback — the same discipline as kernels.gram.ops.
 the fast CPU path of the fused sweep engine.  `use_pallas=True` routes to
 the Pallas kernels; `interpret=None` auto-selects compiled-vs-interpreter
 from the JAX backend via kernels.runtime.resolve_interpret (compiled on TPU,
-interpreter elsewhere), overridable per call or process-wide through
-REPRO_KERNEL_INTERPRET.
+interpreter elsewhere), overridable per call.
 
 Packing contract (see kernel.py): D-vectors ride as (Dp, 8) column packs,
 N-vectors as (8, Np) row packs, scalars on an (8, 128) parameter plate; all

@@ -37,11 +37,16 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.analysis import sanitize
 
 __all__ = ["probe_etas_closed", "probe_sweep_ref", "commit_sweep_ref"]
+
+# N-length contractions at full f32 precision (the TPU default rounds
+# through bf16 passes), matching the kernels they validate
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def probe_etas_closed(m_inv: jnp.ndarray, s: jnp.ndarray, eta: jnp.ndarray,
@@ -78,9 +83,9 @@ def probe_sweep_ref(r_sub: jnp.ndarray, m_inv: jnp.ndarray, s: jnp.ndarray,
     them in one pass with r_sub resident in VMEM.
     """
     m = r_sub.shape[1]
-    cross = s @ r_sub
-    p_acc = r_sub @ cross                      # = m * A0 @ s  (pure Gram)
-    gg_cross = jnp.vdot(cross, cross)
+    cross = jnp.matmul(s, r_sub, precision=_HIGHEST)
+    p_acc = jnp.matmul(r_sub, cross, precision=_HIGHEST)  # = m * A0 @ s
+    gg_cross = jnp.vdot(cross, cross, precision=_HIGHEST)
     scale = (2.0 / m) * s[i]
     gnorm = jnp.sqrt(gg_cross) * jnp.abs(scale) + 1e-30
     p = (scale / (m * gnorm)) * p_acc          # R @ g_unit / m
@@ -105,8 +110,8 @@ def commit_sweep_ref(r_sub: jnp.ndarray, m_inv: jnp.ndarray, s: jnp.ndarray,
     folded into the coefficients — rejection is an exact no-op.
     """
     m = r_sub.shape[1]
-    w = (r_sub @ delta) / m
-    dd_auto = jnp.vdot(delta, delta) / (2.0 * m)
+    w = jnp.matmul(r_sub, delta, precision=_HIGHEST) / m
+    dd_auto = jnp.vdot(delta, delta, precision=_HIGHEST) / (2.0 * m)
     u = w.at[i].set(diag_keep * (w[i] + dd_auto) + diag_add)
 
     z1 = m_inv[i]

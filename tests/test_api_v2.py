@@ -200,7 +200,7 @@ def test_batch_fit_matches_serial_fit_per_trial(mc_spec):
 def test_batch_fit_f64_machine_precision(mc_spec):
     """The acceptance bar: one compiled program, per-trial histories equal to
     8 serial fit() calls at machine precision in f64."""
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         api.clear_dataset_cache()      # drop any f32-built datasets
         try:
             rs = api.batch_fit(mc_spec, 8)

@@ -163,7 +163,7 @@ def test_robust_weights_batches_under_vmap():
     give exactly the per-trial answers (no host sync, no cross-batch leak).
     f64 so only genuine semantic divergence could fail the bound (f32 shows
     harmless batched-matmul reduction-order noise ~1e-4)."""
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         keys = jax.random.split(jax.random.PRNGKey(5), 3)
         r = jax.vmap(lambda k: jax.random.normal(k, (4, 50)))(keys)
         a0s = jnp.einsum("bdn,ben->bde", r, r) / 50.0

@@ -28,7 +28,7 @@ def _rebuild(r_full, idx, dtype):
 @pytest.mark.parametrize("split", [False, True])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
 def test_replace_row_matches_dense_rebuild(d, n, seed, split, dtype):
-    with jax.experimental.enable_x64(dtype == jnp.float64):
+    with jax.enable_x64(dtype == jnp.float64):
         r = _residuals(seed, d, n, dtype)
         idx = jnp.arange(0, n, 4) if split else None
         cs = _rebuild(r, idx, dtype)

@@ -287,7 +287,9 @@ def test_retry_and_skip_both_move_the_ledger():
     clean = api.fit(_spec()).history.bytes_transmitted[1:]
     assert len(set(clean)) == 1                   # reliable wire: constant
     b0 = clean[0]
-    faulted = api.fit(_spec(faults=_FAULTS, n_sweeps=6)
+    # 10 sweeps: under jax's partitionable threefry draws, seed 5's first
+    # net-skip sweep (skips outweighing retries) is sweep 9
+    faulted = api.fit(_spec(faults=_FAULTS, n_sweeps=10)
                       ).history.bytes_transmitted[1:]
     assert max(faulted) > b0                      # charged retransmits
     assert min(faulted) < b0                      # skipped broadcasts

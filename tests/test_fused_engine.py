@@ -60,7 +60,7 @@ def _assert_parity(inc, fused, rtol, atol=0.0):
     dict(step0=0.5, backtrack=0.7, max_probes=6),      # non-default probes
 ])
 def test_fused_matches_incremental_f64(alpha, sched):
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         inc, fused = _run_pair(dict(n_sweeps=4, alpha=alpha, **sched))
     _assert_parity(inc, fused, rtol=1e-10, atol=1e-12)
 
@@ -70,7 +70,7 @@ def test_fused_matches_incremental_f64_lossy_codec():
     plumbing), so lossy transport must not break parity."""
     tp = Transport(topology=build_topology("full", 5),
                    codec=build_codec("int8_affine"))
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         inc, fused = _run_pair(dict(n_sweeps=3, transport=tp))
     _assert_parity(inc, fused, rtol=1e-10, atol=1e-12)
 
@@ -81,7 +81,7 @@ def test_fused_matches_incremental_f64_budget_gated():
     tp = Transport(topology=build_topology("full", 5),
                    codec=build_codec("exact_f64"),
                    byte_budget=2 * 5 * 600 * 8.0 + 3 * 600 * 8.0)
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         inc, fused = _run_pair(dict(n_sweeps=3, transport=tp))
     _assert_parity(inc, fused, rtol=1e-10, atol=1e-12)
     # the ledger gate actually fired (otherwise this test gates nothing):

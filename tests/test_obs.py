@@ -148,7 +148,7 @@ def test_metrics_schema_shapes_and_dtypes():
 
 
 def test_eta_tap_matches_history_fit_f64():
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         api.clear_dataset_cache()
         res = api.fit(_spec(taps=("eta", "s")))
         eta_hist = np.asarray(res.history.eta[1:])
@@ -161,7 +161,7 @@ def test_eta_tap_matches_history_fit_f64():
 
 
 def test_eta_tap_matches_history_batch_fit_vmap_f64():
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         api.clear_dataset_cache()
         spec = _spec(taps=("eta", "accepts"))
         rs = api.batch_fit(spec, 3)
@@ -236,7 +236,7 @@ def test_shard_map_tap_parity_subprocess():
 
 
 def test_stream_taps_concatenate_across_resweeps_f64():
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         api.clear_dataset_cache()
         res = stream_fit(_stream_spec(taps=("eta", "accepts")))
         assert res.metrics is not None

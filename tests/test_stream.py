@@ -36,7 +36,7 @@ def _rand_state(key, d=5, m=32):
 
 
 def test_replace_col_matches_build_f64():
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         key = jax.random.PRNGKey(0)
         st = _rand_state(key)
         c_new = jax.random.normal(jax.random.fold_in(key, 1), (5,))
@@ -51,7 +51,7 @@ def test_replace_col_matches_build_f64():
 def test_replace_col_zero_column_is_pure_append_f64():
     # the ring's warm-up regime: evicting an all-zero placeholder column must
     # be an exact no-op downdate
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         key = jax.random.PRNGKey(1)
         r = jax.random.normal(key, (4, 16)).at[:, 7].set(0.0)
         st = covstate.build(r)
@@ -67,7 +67,7 @@ def test_replace_col_zero_column_is_pure_append_f64():
 
 def test_replace_col_sequential_commits_bounded_drift_f64():
     # a full ring's worth of commits between refreshes stays at solver scale
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         key = jax.random.PRNGKey(2)
         st = _rand_state(key, d=4, m=24)
         r = st.r_sub
@@ -94,7 +94,7 @@ def _stream_spec(**kw):
 
 def test_stream_then_resweep_matches_offline_fit_f64():
     """Ingest N rows one at a time, resweep == api.fit on the same N rows."""
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         api.clear_dataset_cache()
         spec = _stream_spec(window=384, chunk=1, total_instances=256,
                             resweep_every=256, sweeps_per_resweep=5)

@@ -43,10 +43,14 @@ fi
 
 # 3) Persistent compilation cache: first-call numbers in a fresh process
 #    otherwise include XLA compile time; a warm on-disk cache makes the
-#    warmup call cheap and keeps the timed region pure execute.  JAX only
-#    writes entries over ~1s compile time by default; threshold 0 caches
-#    everything the benchmarks build.
-export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-${TMPDIR:-/tmp}/repro-jax-cache}"
+#    warmup call cheap and keeps the timed region pure execute.  The cache
+#    key includes its directory, so it defaults to the checkout's fixed
+#    .jax_cache/ (the same place chip_smoke.py and benchmarks/run.py use).
+#    JAX only writes entries over ~1s compile time by default; threshold 0
+#    caches everything the benchmarks build.
+_repo="$(cd "$(dirname "${BASH_SOURCE[0]}")/.." && pwd)"
+export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-${_repo}/.jax_cache}"
+unset _repo
 export JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="${JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS:-0}"
 mkdir -p "${JAX_COMPILATION_CACHE_DIR}"
 
