@@ -60,6 +60,7 @@ from repro.core import baselines, distributed, icoa
 from repro.data import sources as data_sources
 from repro.launch.mesh import make_trial_mesh
 from repro.obs import taps as obs_taps
+from repro.obs.trace import trace as _obs_span
 
 from repro.api.result import History, Result, ResultSet
 from repro.api.solvers import _bytes_history, _mesh
@@ -366,42 +367,77 @@ def batch_fit(spec: ExperimentSpec, n_trials: int, *,
     spec cannot compile.  Per-trial histories of every path agree to machine
     precision; the compiled paths ignore `solver.eps` (static schedule) but
     report the serial stopping record as `History.converged_at`.
+
+    Traced as the `api.batch_fit` span (obs.trace).  On the compiled paths
+    four child spans cover it one after another: `batch_fit.launch`
+    (validation, the program memo, the asynchronous call into the program;
+    tag `new_program`: the memo missed, so this call traced the program),
+    `batch_fit.wait` (the host blocked on the device), `batch_fit.fetch`
+    (device-to-host copies of the histories) and `batch_fit.assemble` (the
+    per-trial `Result`s).  The serial path's children are its `api.fit`
+    spans.
     """
+    if compiled is None:
+        compiled = _can_compile(spec)
+    with _obs_span("api.batch_fit", n_trials=n_trials,
+                   solver=spec.solver.name, backend=spec.backend.name):
+        if not compiled:
+            _check_batch_args(spec, n_trials)
+            from repro.api import fit  # local import: api.__init__ imports this module
+
+            return ResultSet(spec, [fit(trial_spec(spec, t))
+                                    for t in range(n_trials)])
+        with _obs_span("batch_fit.launch") as launch:
+            _check_batch_args(spec, n_trials)
+            misses = _jitted_batch_program.cache_info().misses
+            if spec.backend.name == "shard_map":
+                out = _batch_shard_map(spec, n_trials)
+            else:
+                out = _batch_local(spec, n_trials)
+            launch["new_program"] = (
+                _jitted_batch_program.cache_info().misses != misses)
+        with _obs_span("batch_fit.wait"):
+            jax.block_until_ready(out)
+        with _obs_span("batch_fit.fetch"):
+            # one bulk device-to-host transfer per history field, not one
+            # per scalar
+            host = {k: np.asarray(out[k]) for k in (
+                "train_mse", "test_mse", "eta", "bytes", "converged_at")
+                if k in out}
+            if out.get("taps"):
+                host["taps"] = {k: np.asarray(v)
+                                for k, v in out["taps"].items()}
+        with _obs_span("batch_fit.assemble", trials=n_trials):
+            return _assemble(spec, n_trials, out, host)
+
+
+def _check_batch_args(spec: ExperimentSpec, n_trials: int) -> None:
     spec.validate()
     if n_trials < 1:
         raise SpecError(f"need n_trials >= 1, got {n_trials}")
-    if compiled is None:
-        compiled = _can_compile(spec)
-    if not compiled:
-        from repro.api import fit  # local import: api.__init__ imports this module
 
-        return ResultSet(spec, [fit(trial_spec(spec, t)) for t in range(n_trials)])
 
-    if spec.backend.name == "shard_map":
-        out = _batch_shard_map(spec, n_trials)
-    else:
-        out = _batch_local(spec, n_trials)
-
+def _assemble(spec: ExperimentSpec, n_trials: int, out: Dict[str, Any],
+              host: Dict[str, Any]) -> ResultSet:
+    """The per-trial `Result`s of a compiled batch: histories from the
+    fetched host arrays, params/weights/f as device slices of `out`."""
     groups = spec.data.groups
     family = spec.agent.resolve(n_cols=len(groups[0]))
     d, n = len(groups), spec.data.n_train
-    n_records = out["train_mse"].shape[1]
+    n_records = host["train_mse"].shape[1]
     # icoa scans return the MEASURED per-sweep ledger; the baselines have no
     # traced ledger (averaging: zero traffic, refit: constant psum price)
-    bytes_meas = np.asarray(out["bytes"]) if "bytes" in out else None
-    bytes_hist = None if bytes_meas is not None else _bytes_history(
-        spec, d, n, n_records,
-        initial_record=spec.solver.name != "residual_refitting")
-
-    # one bulk device-to-host transfer per history field, not one per scalar
-    host = {k: np.asarray(out[k]) for k in ("train_mse", "test_mse", "eta")}
-    conv = np.asarray(out["converged_at"]) if "converged_at" in out else None
+    bytes_meas = host.get("bytes")
+    conv = host.get("converged_at")
     # collected obs taps ride the out dict as one more stacked pytree: the
     # trial axis lands in front of the per-sweep axis (vmap/scan semantics),
     # so trial t's Metrics is a plain leading-axis slice
+    taps_host = host.get("taps")
+    bytes_hist = None if bytes_meas is not None else _bytes_history(
+        spec, d, n, n_records,
+        initial_record=spec.solver.name != "residual_refitting")
     obs_norm = spec.obs.normalized()
-    taps_host = ({k: np.asarray(v) for k, v in out["taps"].items()}
-                 if out.get("taps") else None)
+
     def take(tree, t):
         return jax.tree.map(lambda a: a[t], tree)
 

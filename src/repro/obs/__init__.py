@@ -23,11 +23,10 @@ from __future__ import annotations
 from repro.obs.health import Counter, LatencyRing, prometheus_text
 from repro.obs.spec import ALL_TAPS, TAPS, ObsError, ObsSpec
 from repro.obs.taps import Metrics
-from repro.obs.trace import (Tracer, active, configure, disable, event, step,
-                             trace)
+from repro.obs.trace import Tracer, active, configure, disable, event, trace
 
 __all__ = [
     "ALL_TAPS", "Counter", "LatencyRing", "Metrics", "ObsError", "ObsSpec",
     "TAPS", "Tracer", "active", "configure", "disable", "event",
-    "prometheus_text", "step", "trace",
+    "prometheus_text", "trace",
 ]

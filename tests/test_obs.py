@@ -15,6 +15,7 @@ import math
 import os
 import subprocess
 import sys
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -447,6 +448,139 @@ def test_api_fit_emits_a_span_when_configured(tmp_path):
                  if r["ev"] == "span" and r["name"] == "api.fit"]
     assert len(fit_spans) == 1
     assert fit_spans[0]["tags"]["solver"] == "icoa"
+
+
+def test_spans_carry_ids_and_same_thread_parents(tmp_path):
+    path = str(tmp_path / "ids.jsonl")
+    configure(path)
+    try:
+        with trace("outer"):
+            with trace("mid"):
+                with trace("leaf"):
+                    pass
+            with trace("sibling") as tags:
+                tags["count"] = 2
+            # a span on another thread has no parent here: the stack of
+            # open spans is per thread
+            worker = threading.Thread(target=_span_on_thread)
+            worker.start()
+            worker.join(timeout=60)
+        with trace("second-root"):
+            pass
+    finally:
+        disable()
+    assert not worker.is_alive()
+    rows = {r["name"]: r for r in map(json.loads, open(path))}
+    assert len({r["id"] for r in rows.values()}) == len(rows) == 6
+    assert rows["outer"]["parent"] is None
+    assert rows["second-root"]["parent"] is None
+    assert rows["mid"]["parent"] == rows["outer"]["id"]
+    assert rows["leaf"]["parent"] == rows["mid"]["id"]
+    assert rows["sibling"]["parent"] == rows["outer"]["id"]
+    assert rows["sibling"]["tags"] == {"count": 2}
+    assert rows["thread-span"]["parent"] is None
+    # ids number the spans in the order they opened
+    order = ["outer", "mid", "leaf", "sibling", "thread-span", "second-root"]
+    assert [rows[n]["id"] for n in order] == sorted(
+        r["id"] for r in rows.values())
+
+
+def _span_on_thread():
+    with trace("thread-span"):
+        pass
+
+
+def test_rows_are_written_on_disable_and_at_exit(tmp_path):
+    path = str(tmp_path / "buffered.jsonl")
+    configure(path)
+    try:
+        with trace("held"):
+            event("mark")
+        # nothing is written while the sink is armed
+        assert open(path).read() == ""
+    finally:
+        disable()
+    assert [json.loads(l)["name"] for l in open(path)] == ["mark", "held"]
+    # a process that exits with the sink armed still writes its rows
+    exiting = str(tmp_path / "exit.jsonl")
+    code = ("import sys\nfrom repro import obs\nobs.configure(sys.argv[1])\n"
+            "with obs.trace('at-exit', k=1):\n    pass\n")
+    out = subprocess.run([sys.executable, "-c", code, exiting],
+                         capture_output=True, text=True, timeout=120,
+                         env=dict(os.environ, PYTHONPATH=os.path.join(
+                             REPO, "src")))
+    assert out.returncode == 0, out.stderr
+    rows = [json.loads(l) for l in open(exiting)]
+    assert [(r["name"], r["tags"], r["parent"]) for r in rows] == [
+        ("at-exit", {"k": 1}, None)]
+
+
+_BATCH_PHASES = ("batch_fit.launch", "batch_fit.wait", "batch_fit.fetch",
+                 "batch_fit.assemble")
+
+
+def test_batch_fit_spans_cover_the_call_in_order(tmp_path):
+    # a seed of its own, so the program memo has not seen this spec
+    spec = api.replace(_spec(), seed=1313)
+    path = str(tmp_path / "batch.jsonl")
+    configure(path)
+    try:
+        api.batch_fit(spec, 3)
+        api.batch_fit(spec, 3)
+    finally:
+        disable()
+    rows = [json.loads(l) for l in open(path)]
+    parents = [r for r in rows if r["name"] == "api.batch_fit"]
+    assert len(parents) == 2
+    for call, parent in enumerate(parents):
+        assert parent["parent"] is None
+        assert parent["tags"] == {"n_trials": 3, "solver": "icoa",
+                                  "backend": "local"}
+        kids = sorted((r for r in rows if r["parent"] == parent["id"]),
+                      key=lambda r: r["id"])
+        assert tuple(r["name"] for r in kids) == _BATCH_PHASES
+        # `t` is the wall clock and `dur_s` the monotonic one: allow their
+        # drift over the call
+        eps = 1e-4
+        assert kids[0]["t"] >= parent["t"] - eps
+        assert kids[-1]["t"] + kids[-1]["dur_s"] <= (
+            parent["t"] + parent["dur_s"] + eps)
+        for a, b in zip(kids, kids[1:]):
+            assert a["t"] + a["dur_s"] <= b["t"] + eps
+        assert sum(k["dur_s"] for k in kids) <= parent["dur_s"]
+        assert kids[0]["tags"] == {"new_program": call == 0}
+        assert kids[-1]["tags"] == {"trials": 3}
+
+
+def test_serial_batch_fit_nests_its_api_fit_spans(tmp_path):
+    path = str(tmp_path / "serial.jsonl")
+    configure(path)
+    try:
+        api.batch_fit(_spec(), 2, compiled=False)
+    finally:
+        disable()
+    rows = [json.loads(l) for l in open(path)]
+    (parent,) = [r for r in rows if r["name"] == "api.batch_fit"]
+    kids = [r["name"] for r in rows if r["parent"] == parent["id"]]
+    assert kids == ["api.fit", "api.fit"]
+
+
+def test_batch_fit_spans_land_on_the_profiler_host_plane(tmp_path):
+    from jax.profiler import ProfileData
+
+    spec = _spec()
+    api.batch_fit(spec, 2)                      # compile outside the capture
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        api.batch_fit(spec, 2)
+    finally:
+        jax.profiler.stop_trace()
+    (pb,) = list(tmp_path.rglob("*.xplane.pb"))
+    names = set()
+    for plane in ProfileData.from_file(str(pb)).planes:
+        if plane.name.startswith("/host:CPU"):
+            names.update(e.name for line in plane.lines for e in line.events)
+    assert {"api.batch_fit", *_BATCH_PHASES} <= names
 
 
 def test_stream_fit_event_log_renders_through_obs_report(tmp_path):
