@@ -61,6 +61,11 @@ class History:
 
 @dataclasses.dataclass
 class Result:
+    """One run.  `fit()` (and the serial `batch_fit` path) leaves params,
+    weights and f as device arrays; a compiled `batch_fit` gives host
+    `np.ndarray` views of trial t's row of one bulk fetch.  `predict`,
+    `mse` and `save` take either as they are."""
+
     spec: ExperimentSpec
     family: Any               # resolved agent family (static dataclass)
     params: Any               # stacked agent params, leading dim D

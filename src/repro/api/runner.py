@@ -51,7 +51,6 @@ from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import checkify
 from jax.sharding import PartitionSpec as P
 
@@ -373,9 +372,10 @@ def batch_fit(spec: ExperimentSpec, n_trials: int, *,
     (validation, the program memo, the asynchronous call into the program;
     tag `new_program`: the memo missed, so this call traced the program),
     `batch_fit.wait` (the host blocked on the device), `batch_fit.fetch`
-    (device-to-host copies of the histories) and `batch_fit.assemble` (the
-    per-trial `Result`s).  The serial path's children are its `api.fit`
-    spans.
+    (one bulk device-to-host copy of the histories, params, weights and f;
+    tag `host_bytes`: the bytes it copied) and `batch_fit.assemble` (the
+    per-trial `Result`s, host views of the fetched arrays).  The serial
+    path's children are its `api.fit` spans.
     """
     if compiled is None:
         compiled = _can_compile(spec)
@@ -398,17 +398,15 @@ def batch_fit(spec: ExperimentSpec, n_trials: int, *,
                 _jitted_batch_program.cache_info().misses != misses)
         with _obs_span("batch_fit.wait"):
             jax.block_until_ready(out)
-        with _obs_span("batch_fit.fetch"):
-            # one bulk device-to-host transfer per history field, not one
-            # per scalar
-            host = {k: np.asarray(out[k]) for k in (
-                "train_mse", "test_mse", "eta", "bytes", "converged_at")
-                if k in out}
-            if out.get("taps"):
-                host["taps"] = {k: np.asarray(v)
-                                for k, v in out["taps"].items()}
+        with _obs_span("batch_fit.fetch") as fetch:
+            # `out` holds exactly what the Results need: one bulk
+            # device-to-host copy of all of it, issued together, so that
+            # assembly slices host arrays only
+            host = jax.device_get(out)
+            fetch["host_bytes"] = int(sum(
+                a.nbytes for a in jax.tree.leaves(host)))
         with _obs_span("batch_fit.assemble", trials=n_trials):
-            return _assemble(spec, n_trials, out, host)
+            return _assemble(spec, n_trials, host)
 
 
 def _check_batch_args(spec: ExperimentSpec, n_trials: int) -> None:
@@ -417,10 +415,11 @@ def _check_batch_args(spec: ExperimentSpec, n_trials: int) -> None:
         raise SpecError(f"need n_trials >= 1, got {n_trials}")
 
 
-def _assemble(spec: ExperimentSpec, n_trials: int, out: Dict[str, Any],
+def _assemble(spec: ExperimentSpec, n_trials: int,
               host: Dict[str, Any]) -> ResultSet:
-    """The per-trial `Result`s of a compiled batch: histories from the
-    fetched host arrays, params/weights/f as device slices of `out`."""
+    """The per-trial `Result`s of a compiled batch, all from the host arrays
+    of one fetch: histories as lists, params/weights/f as NumPy views of
+    trial t's row (no device work per trial)."""
     groups = spec.data.groups
     family = spec.agent.resolve(n_cols=len(groups[0]))
     d, n = len(groups), spec.data.n_train
@@ -432,28 +431,26 @@ def _assemble(spec: ExperimentSpec, n_trials: int, out: Dict[str, Any],
     # collected obs taps ride the out dict as one more stacked pytree: the
     # trial axis lands in front of the per-sweep axis (vmap/scan semantics),
     # so trial t's Metrics is a plain leading-axis slice
-    taps_host = host.get("taps")
+    taps_host = host.get("taps") or None
     bytes_hist = None if bytes_meas is not None else _bytes_history(
         spec, d, n, n_records,
         initial_record=spec.solver.name != "residual_refitting")
     obs_norm = spec.obs.normalized()
 
-    def take(tree, t):
-        return jax.tree.map(lambda a: a[t], tree)
-
     results = []
     for t in range(n_trials):
         history = History(
-            train_mse=[float(v) for v in host["train_mse"][t]],
-            test_mse=[float(v) for v in host["test_mse"][t]],
-            eta=[float(v) for v in host["eta"][t]],
+            train_mse=host["train_mse"][t].tolist(),
+            test_mse=host["test_mse"][t].tolist(),
+            eta=host["eta"][t].tolist(),
             bytes_transmitted=(list(bytes_hist) if bytes_meas is None
-                               else [float(v) for v in bytes_meas[t]]),
+                               else bytes_meas[t].astype(float).tolist()),
             converged_at=None if conv is None else int(conv[t]))
         metrics = None if taps_host is None else obs_taps.metrics_from_taps(
             obs_norm, {k: v[t] for k, v in taps_host.items()})
         results.append(Result(
             spec=trial_spec(spec, t), family=family,
-            params=take(out["params"], t), weights=out["weights"][t],
-            f=out["f"][t], history=history, data=None, metrics=metrics))
+            params=jax.tree.map(lambda a: a[t], host["params"]),
+            weights=host["weights"][t], f=host["f"][t], history=history,
+            data=None, metrics=metrics))
     return ResultSet(spec, results)

@@ -195,3 +195,22 @@ def test_save_load_roundtrip(tmp_path, base_spec):
                                rtol=1e-6)
     xte = jnp.concatenate([res.data.xcols_test[i] for i in range(5)], axis=1)
     assert back.mse(xte, res.data.y_test) == pytest.approx(res.test_mse, rel=1e-5)
+
+
+def test_save_load_roundtrip_of_a_batch_result(tmp_path, base_spec):
+    """A compiled-batch Result holds host arrays; they save and restore
+    exactly, and predict and mse work on both sides."""
+    res = api.batch_fit(base_spec, 2)[1]
+    res.save(str(tmp_path))
+    back = api.load(str(tmp_path), with_data=False)
+    assert back.spec == res.spec
+    assert back.history.as_dict() == res.history.as_dict()
+    for a, b in zip(jax.tree.leaves(back.params), jax.tree.leaves(res.params)):
+        np.testing.assert_array_equal(np.asarray(a), b)
+    np.testing.assert_array_equal(np.asarray(back.weights), res.weights)
+    np.testing.assert_array_equal(np.asarray(back.f), res.f)
+    data = res.spec.data.build()
+    x = jnp.concatenate(list(data.xcols_test), axis=1)
+    np.testing.assert_array_equal(np.asarray(back.predict(x)),
+                                  np.asarray(res.predict(x)))
+    assert back.mse(x, data.y_test) == pytest.approx(res.test_mse, rel=1e-5)

@@ -215,6 +215,43 @@ def test_batch_fit_f64_machine_precision(mc_spec):
             api.clear_dataset_cache()  # don't leak f64 datasets to other tests
 
 
+def test_batch_fit_per_trial_arrays_match_serial_f64(mc_spec):
+    """Each trial's params, weights and f (not only its histories) are the
+    serial fit()'s, and so is what predict makes of them."""
+    with jax.enable_x64(True):
+        api.clear_dataset_cache()
+        try:
+            rs = api.batch_fit(mc_spec, 4)
+            data = api.trial_spec(mc_spec, 0).data.build()
+            x = jnp.concatenate(list(data.xcols_test), axis=1)
+            for t in range(4):
+                ser = api.fit(api.trial_spec(mc_spec, t))
+                for a, b in zip(jax.tree.leaves(rs[t].params),
+                                jax.tree.leaves(ser.params)):
+                    np.testing.assert_allclose(a, b, rtol=1e-10)
+                np.testing.assert_allclose(rs[t].weights, ser.weights,
+                                           rtol=1e-10)
+                np.testing.assert_allclose(rs[t].f, ser.f, rtol=1e-10)
+                np.testing.assert_allclose(rs[t].predict(x), ser.predict(x),
+                                           rtol=1e-10)
+        finally:
+            api.clear_dataset_cache()
+
+
+def test_batch_fit_results_are_host_views_of_one_fetch(mc_spec):
+    rs = api.batch_fit(mc_spec, 3)
+    for field in ("params", "weights", "f"):
+        leaves = [jax.tree.leaves(getattr(r, field)) for r in rs]
+        for per_trial in zip(*leaves):
+            assert all(type(a) is np.ndarray for a in per_trial), field
+            # the trials' rows of one fetched array, not copies of their own
+            fetched = per_trial[0].base
+            assert isinstance(fetched, np.ndarray), field
+            assert fetched.shape == (3, *per_trial[0].shape), field
+            assert all(a.base is fetched and np.shares_memory(a, fetched)
+                       for a in per_trial), field
+
+
 def test_batch_fit_baselines_and_forced_serial(mc_spec):
     for name in ("averaging", "residual_refitting"):
         spec = api.spec_with(mc_spec, "solver.name", name)

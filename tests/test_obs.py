@@ -529,6 +529,12 @@ def test_batch_fit_spans_cover_the_call_in_order(tmp_path):
         api.batch_fit(spec, 3)
     finally:
         disable()
+    # the fetch copies, per trial: train/test/eta and the int32 byte ledger
+    # (R records each), converged_at, params (D x degree+1), weights, f;
+    # all 4-byte words in the default f32
+    trials, d, records = 3, 5, spec.solver.n_sweeps + 1
+    words = 4 * records + 1 + d * (3 + 1) + d + d * _N
+    host_bytes = 4 * trials * words
     rows = [json.loads(l) for l in open(path)]
     parents = [r for r in rows if r["name"] == "api.batch_fit"]
     assert len(parents) == 2
@@ -549,6 +555,7 @@ def test_batch_fit_spans_cover_the_call_in_order(tmp_path):
             assert a["t"] + a["dur_s"] <= b["t"] + eps
         assert sum(k["dur_s"] for k in kids) <= parent["dur_s"]
         assert kids[0]["tags"] == {"new_program": call == 0}
+        assert kids[2]["tags"] == {"host_bytes": host_bytes}
         assert kids[-1]["tags"] == {"trials": 3}
 
 
