@@ -367,20 +367,25 @@ def batch_fit(spec: ExperimentSpec, n_trials: int, *,
     precision; the compiled paths ignore `solver.eps` (static schedule) but
     report the serial stopping record as `History.converged_at`.
 
-    Traced as the `api.batch_fit` span (obs.trace).  On the compiled paths
-    four child spans cover it one after another: `batch_fit.launch`
-    (validation, the program memo, the asynchronous call into the program;
-    tag `new_program`: the memo missed, so this call traced the program),
-    `batch_fit.wait` (the host blocked on the device), `batch_fit.fetch`
-    (one bulk device-to-host copy of the histories, params, weights and f;
-    tag `host_bytes`: the bytes it copied) and `batch_fit.assemble` (the
-    per-trial `Result`s, host views of the fetched arrays).  The serial
+    Traced as the `api.batch_fit` span (obs.trace; tag `agents_mesh`: the
+    devices one trial's agents span, 1 on the local backend).  On the
+    compiled paths four child spans cover it one after another:
+    `batch_fit.launch` (validation, the program memo, the asynchronous call
+    into the program; tag `new_program`: the memo missed, so this call
+    traced the program), `batch_fit.wait` (the host blocked on the device),
+    `batch_fit.fetch` (one bulk device-to-host copy of the histories,
+    params, weights and f; tag `host_bytes`: the bytes it copied) and
+    `batch_fit.assemble` (the per-trial `Result`s, host views of the fetched
+    arrays; tag `wire_bytes`: the residual bytes the call's trials sent,
+    summed from their histories).  The serial
     path's children are its `api.fit` spans.
     """
     if compiled is None:
         compiled = _can_compile(spec)
     with _obs_span("api.batch_fit", n_trials=n_trials,
-                   solver=spec.solver.name, backend=spec.backend.name):
+                   solver=spec.solver.name, backend=spec.backend.name,
+                   agents_mesh=(len(spec.data.groups)
+                                if spec.backend.name == "shard_map" else 1)):
         if not compiled:
             _check_batch_args(spec, n_trials)
             from repro.api import fit  # local import: api.__init__ imports this module
@@ -405,8 +410,11 @@ def batch_fit(spec: ExperimentSpec, n_trials: int, *,
             host = jax.device_get(out)
             fetch["host_bytes"] = int(sum(
                 a.nbytes for a in jax.tree.leaves(host)))
-        with _obs_span("batch_fit.assemble", trials=n_trials):
-            return _assemble(spec, n_trials, host)
+        with _obs_span("batch_fit.assemble", trials=n_trials) as assemble:
+            rs = _assemble(spec, n_trials, host)
+            assemble["wire_bytes"] = int(sum(
+                sum(r.history.bytes_transmitted) for r in rs.results))
+            return rs
 
 
 def _check_batch_args(spec: ExperimentSpec, n_trials: int) -> None:

@@ -63,6 +63,20 @@ def make_agent_mesh(n_agents: int) -> Mesh:
     return Mesh(__import__("numpy").array(devs[:n_agents]), ("agents",))
 
 
+def _record_gram(mesh: Mesh, use_kernel: bool):
+    """(D, N) residuals, one row per agent's device -> the (D, D) Gram of the
+    record's eta (a diagnostic, not the paper's traffic).  XLA cannot
+    partition a Mosaic kernel, so the Gram runs inside shard_map as the
+    sweep's do: every device gathers the rows and computes it on its copy."""
+
+    def body(r_local):
+        return cov.gram(jax.lax.all_gather(r_local[0], "agents"),
+                        use_kernel=use_kernel)
+
+    return jax.shard_map(body, mesh=mesh, in_specs=P("agents"),
+                         out_specs=P(), check_vma=False)
+
+
 def _gathered_a0(f_sub_all: jnp.ndarray, y_sub: jnp.ndarray, diag_all: jnp.ndarray,
                  alpha: float, tp=None) -> jnp.ndarray:
     """A0 from gathered (possibly subsampled) residuals + exact local diags.
@@ -466,6 +480,7 @@ def run_distributed(family, cfg: ICOAConfig, xcols: jnp.ndarray, y: jnp.ndarray,
         # functionalize the check sites and throw on the first failure
         sweep_fn = sanitize.checked(sweep_fn)
     hist = {"train_mse": [], "test_mse": [], "eta": [], "bytes": [0.0]}
+    record_gram = jax.jit(_record_gram(mesh, cfg.use_kernel))
     key = jax.random.PRNGKey(seed + 1)
     w = jnp.ones((d,), f.dtype) / d
     ledger = Ledger.empty()
@@ -480,7 +495,7 @@ def run_distributed(family, cfg: ICOAConfig, xcols: jnp.ndarray, y: jnp.ndarray,
             hist["test_mse"].append(float(jnp.mean((y_test - w @ preds) ** 2)))
         # same definition as core.icoa.run: eta of the optimally-weighted
         # ensemble on the FULL residual covariance (diagnostic, not traffic)
-        a0r = cov.gram(y[None, :] - f, use_kernel=cfg.use_kernel)
+        a0r = record_gram(y[None, :] - f)
         hist["eta"].append(float(ensemble.eta(a0r)))
         if rec_obs:
             return obs_taps.record_taps(cfg.obs, ensemble.eta(a0r),
@@ -534,6 +549,7 @@ def run_scan_distributed(family, cfg: ICOAConfig, xcols: jnp.ndarray,
     f = jax.vmap(family.predict)(params, xcols)
 
     sweep_fn = _sweep_shmap(mesh, cfg, family)
+    record_gram = _record_gram(mesh, cfg.use_kernel)
     rec_obs = cfg.obs is not None and ("eta" in cfg.obs.taps
                                        or "s" in cfg.obs.taps)
 
@@ -541,7 +557,7 @@ def run_scan_distributed(family, cfg: ICOAConfig, xcols: jnp.ndarray,
         train = jnp.mean((y - w @ f) ** 2)
         preds = jax.vmap(family.predict)(params, xcols_test)
         test = jnp.mean((y_test - w @ preds) ** 2)
-        a0r = cov.gram(y[None, :] - f, use_kernel=cfg.use_kernel)
+        a0r = record_gram(y[None, :] - f)
         eta = ensemble.eta(a0r)
         rtaps = (obs_taps.record_taps(cfg.obs, eta, ensemble.solve_vec(a0r))
                  if rec_obs else {})

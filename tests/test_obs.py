@@ -526,9 +526,11 @@ def test_batch_fit_spans_cover_the_call_in_order(tmp_path):
     configure(path)
     try:
         api.batch_fit(spec, 3)
-        api.batch_fit(spec, 3)
+        rs = api.batch_fit(spec, 3)
     finally:
         disable()
+    wire_bytes = int(sum(sum(r.history.bytes_transmitted)
+                         for r in rs.results))
     # the fetch copies, per trial: train/test/eta and the int32 byte ledger
     # (R records each), converged_at, params (D x degree+1), weights, f;
     # all 4-byte words in the default f32
@@ -541,7 +543,7 @@ def test_batch_fit_spans_cover_the_call_in_order(tmp_path):
     for call, parent in enumerate(parents):
         assert parent["parent"] is None
         assert parent["tags"] == {"n_trials": 3, "solver": "icoa",
-                                  "backend": "local"}
+                                  "backend": "local", "agents_mesh": 1}
         kids = sorted((r for r in rows if r["parent"] == parent["id"]),
                       key=lambda r: r["id"])
         assert tuple(r["name"] for r in kids) == _BATCH_PHASES
@@ -556,7 +558,16 @@ def test_batch_fit_spans_cover_the_call_in_order(tmp_path):
         assert sum(k["dur_s"] for k in kids) <= parent["dur_s"]
         assert kids[0]["tags"] == {"new_program": call == 0}
         assert kids[2]["tags"] == {"host_bytes": host_bytes}
-        assert kids[-1]["tags"] == {"trials": 3}
+        assert kids[-1]["tags"] == {"trials": 3, "wire_bytes": wire_bytes}
+    assert wire_bytes > 0
+
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "tools", "obs_report.py"), path],
+        capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stdout + out.stderr
+    table = out.stdout.split("== batch_fit calls ==")[1].splitlines()
+    assert table[2].split() == ["local", "1", "2", "6", table[2].split()[4],
+                                str(2 * wire_bytes)]
 
 
 def test_serial_batch_fit_nests_its_api_fit_spans(tmp_path):

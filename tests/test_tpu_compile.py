@@ -28,11 +28,11 @@ F32 = jnp.float32
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
@@ -40,8 +40,25 @@ def one_chip():
     # never be read back without one; keep the cache out of these compiles
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def mosaic_kernels(monkeypatch):
+    """This process's backend is the CPU, so the ops would pick the
+    interpreter; force the compiled kernels the TPU backend would pick."""
+    from repro.kernels.gram import ops as gram_ops
+    from repro.kernels.sweep import ops as sweep_ops
+
+    for ops in (gram_ops, sweep_ops):
+        monkeypatch.setattr(ops, "resolve_interpret",
+                            lambda explicit=None: False)
 
 
 def _shapes(sharding, *shapes):
@@ -89,17 +106,11 @@ def test_kernel_compiles_for_v5e(one_chip, name, dp):
     assert "tpu_custom_call" in text
 
 
-def test_fit_runner_carries_kernels_for_v5e(one_chip, monkeypatch):
+def test_fit_runner_carries_kernels_for_v5e(one_chip, mosaic_kernels):
     """The compiled fused-engine program of the on-chip smoke's fit spec
     (D=100 cosine parties, 64K instances) calls the Mosaic kernels."""
     from repro import api
-    from repro.kernels.gram import ops as gram_ops
-    from repro.kernels.sweep import ops as sweep_ops
 
-    # this process's backend is the CPU, so the ops would pick the
-    # interpreter; force the compiled kernels the TPU backend would pick
-    for ops in (gram_ops, sweep_ops):
-        monkeypatch.setattr(ops, "resolve_interpret", lambda explicit=None: False)
     spec = api.ExperimentSpec(
         data=api.DataSpec(source="cosine", n_attrs=100, n_train=NP,
                           n_test=NP),
@@ -109,3 +120,34 @@ def test_fit_runner_carries_kernels_for_v5e(one_chip, monkeypatch):
     trial = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     text = _compiled_text(api.build_runner(spec), (trial,))
     assert "tpu_custom_call" in text
+
+
+def test_mesh_batch_program_carries_kernels_for_v5e_2x2(topo, mosaic_kernels):
+    """The shard_map backend's Monte-Carlo program for four parties of two
+    columns on the four chips of a v5e:2x2, at 64K instances, with the fused
+    engine's kernels: XLA cannot partition a Mosaic kernel, so every kernel
+    must sit inside a shard_map (the record's Gram included)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro import api
+
+    spec = api.ExperimentSpec(
+        data=api.DataSpec(source="correlated_linear", n_attrs=8,
+                          partition="blocks", n_agents=4, n_train=NP,
+                          n_test=NP),
+        agent=api.AgentSpec(family="polynomial", options=(("degree", 1),)),
+        solver=api.SolverSpec(name="icoa", engine="fused", use_kernel=True,
+                              n_sweeps=10),
+        backend=api.BackendSpec(name="shard_map"))
+    mesh = Mesh(np.array(topo.devices[:4]), ("agents",))
+    run_fn = api.build_distributed_runner(spec, mesh=mesh)
+
+    def loop(trials):
+        return jax.lax.scan(lambda c, t: (c, run_fn(t)),
+                            jnp.asarray(0, jnp.int32), trials)[1]
+
+    trials = jax.ShapeDtypeStruct((2,), jnp.int32,
+                                  sharding=NamedSharding(mesh, PartitionSpec()))
+    text = _compiled_text(loop, (trials,))
+    assert "tpu_custom_call" in text and "all-gather" in text

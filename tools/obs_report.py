@@ -4,11 +4,14 @@ Usage::
 
     python tools/obs_report.py events.jsonl
 
-Three sections, all derived from the `repro.obs.trace` schema
+Four sections, all derived from the `repro.obs.trace` schema
 (``{"ev": "span"|"event", "name": ..., "t": ..., "dur_s": ..., "tags": ...}``):
 
   spans    per-name count / total / mean / max wall seconds — where the run
            actually spent its host time (fit, resweep cadence, checkpoints)
+  batch    the `api.batch_fit` calls by backend and agent mesh size
+           (`agents_mesh`): calls, trials, mean wall seconds and the residual
+           bytes the trials sent (`wire_bytes` of `batch_fit.assemble`)
   metrics  the per-record metric table from `stream.record` events (round,
            instance count, sweeps executed, eta, windowed train MSE,
            prequential MSE, re-sweep wire bytes)
@@ -56,6 +59,30 @@ def span_table(rows: List[Dict[str, Any]]) -> List[str]:
                    f"{sum(ds) / len(ds):>10.4f} {max(ds):>10.4f}")
     if not agg:
         out.append("(no spans)")
+    return out
+
+
+def batch_table(rows: List[Dict[str, Any]]) -> List[str]:
+    calls = {r["id"]: r for r in rows
+             if r.get("ev") == "span" and r["name"] == "api.batch_fit"}
+    wire = {r["parent"]: r["tags"].get("wire_bytes", 0) for r in rows
+            if r.get("ev") == "span" and r["name"] == "batch_fit.assemble"}
+    agg: Dict[tuple, List[tuple]] = defaultdict(list)
+    for sid, r in calls.items():
+        t = r.get("tags", {})
+        key = (t.get("backend", "-"), t.get("agents_mesh", "-"))
+        agg[key].append((t.get("n_trials", 0), float(r.get("dur_s", 0.0)),
+                         wire.get(sid, 0)))
+    out = ["== batch_fit calls ==",
+           f"{'backend':<10} {'agents_mesh':>11} {'calls':>6} {'trials':>8} "
+           f"{'mean_s':>10} {'wire_bytes':>14}"]
+    for (backend, mesh), cs in sorted(agg.items(), key=str):
+        out.append(f"{backend:<10} {mesh:>11} {len(cs):>6} "
+                   f"{sum(c[0] for c in cs):>8} "
+                   f"{sum(c[1] for c in cs) / len(cs):>10.4f} "
+                   f"{sum(c[2] for c in cs):>14}")
+    if not calls:
+        out.append("(no api.batch_fit spans)")
     return out
 
 
@@ -107,6 +134,9 @@ def main(argv: List[str]) -> int:
     print(f"{argv[0]}: {len(rows)} lines"
           + (f", run(s) {', '.join(map(str, runs))}" if runs else ""))
     for line in span_table(rows):
+        print(line)
+    print()
+    for line in batch_table(rows):
         print(line)
     print()
     for line in metric_table(records):
