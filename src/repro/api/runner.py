@@ -56,6 +56,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.analysis import sanitize
 from repro.core import baselines, distributed, icoa
+from repro.core import covariance as cov
 from repro.data import sources as data_sources
 from repro.launch.mesh import make_trial_mesh
 from repro.obs import taps as obs_taps
@@ -367,8 +368,10 @@ def batch_fit(spec: ExperimentSpec, n_trials: int, *,
     precision; the compiled paths ignore `solver.eps` (static schedule) but
     report the serial stopping record as `History.converged_at`.
 
-    Traced as the `api.batch_fit` span (obs.trace; tag `agents_mesh`: the
-    devices one trial's agents span, 1 on the local backend).  On the
+    Traced as the `api.batch_fit` span (obs.trace; tags `agents_mesh`: the
+    devices one trial's agents span, 1 on the local backend; `sub_rows`: the
+    instances each party sends per residual gather, n_train at alpha 1, where
+    the sweep bodies index nothing, else the alpha subsample).  On the
     compiled paths four child spans cover it one after another:
     `batch_fit.launch` (validation, the program memo, the asynchronous call
     into the program; tag `new_program`: the memo missed, so this call
@@ -385,7 +388,11 @@ def batch_fit(spec: ExperimentSpec, n_trials: int, *,
     with _obs_span("api.batch_fit", n_trials=n_trials,
                    solver=spec.solver.name, backend=spec.backend.name,
                    agents_mesh=(len(spec.data.groups)
-                                if spec.backend.name == "shard_map" else 1)):
+                                if spec.backend.name == "shard_map" else 1),
+                   sub_rows=(cov.subsample_size(spec.data.n_train,
+                                                spec.solver.alpha)
+                             if spec.solver.alpha > 1.0
+                             else spec.data.n_train)):
         if not compiled:
             _check_batch_args(spec, n_trials)
             from repro.api import fit  # local import: api.__init__ imports this module

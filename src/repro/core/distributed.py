@@ -10,7 +10,9 @@ This is the paper's system realised as a collective schedule (DESIGN.md §3.1):
     figure (Fig. 2, right);
   * Minimax Protection (alpha > 1) gathers only an N/alpha subsample plus the
     D local variance scalars, shrinking the payload by alpha — the paper's
-    transmission/performance trade-off as a first-class sharding knob;
+    transmission/performance trade-off as a first-class sharding knob; at
+    alpha <= 1 the bodies index nothing and move the full-length rows as
+    they are (`_subsample`, core.icoa's `idx = None` rule);
   * the D x D covariance algebra is replicated (it is tiny); the projection
     re-training runs everywhere but only the owning agent keeps its result
     (a `where` on axis_index), so there is no parameter traffic either.
@@ -96,6 +98,26 @@ def _gathered_a0(f_sub_all: jnp.ndarray, y_sub: jnp.ndarray, diag_all: jnp.ndarr
     return a0
 
 
+def _subsample(cfg: ICOAConfig, key, n: int):
+    """The instances each party sends per residual gather, as both sweep
+    bodies index them: (idx, m, take).  alpha > 1 draws Minimax Protection's
+    random subsample (`cov.subsample_indices` on the second half of
+    `split(key)`, the same key on every device); otherwise idx is None, as
+    in core.icoa, m = n and `take` is the identity, so the bodies move the
+    full-length rows with no gather or scatter."""
+    if cfg.alpha > 1.0:
+        idx = cov.subsample_indices(jax.random.split(key)[1], n, cfg.alpha)
+        return idx, idx.shape[0], lambda a: a[idx]
+    return None, n, lambda a: a
+
+
+def _add_at(f, idx, v):
+    """f with v added at the subsampled positions (everywhere at idx None:
+    the same sum, though a backend may fuse the multiply that forms v into
+    the add, as XLA's CPU backend does)."""
+    return f + v if idx is None else f.at[idx].add(v)
+
+
 def _sweep_body(cfg: ICOAConfig, tp, family, xcol, y, f_local, params_local,
                 key, ledger, round_):
     """Runs INSIDE shard_map. Shapes (local): xcol (1,N,C); f_local (1,N)."""
@@ -104,23 +126,19 @@ def _sweep_body(cfg: ICOAConfig, tp, family, xcol, y, f_local, params_local,
     me = jax.lax.axis_index("agents")
     n = y.shape[0]
 
-    if cfg.alpha > 1.0:
-        key, ksub = jax.random.split(key)
-        idx = cov.subsample_indices(ksub, n, cfg.alpha)   # same key everywhere
-    else:
-        idx = jnp.arange(n)
-    ledger_mod.ensure_sweep_capacity(tp, cfg.n_sweeps, idx.shape[0],
+    idx, m, take = _subsample(cfg, key, n)
+    ledger_mod.ensure_sweep_capacity(tp, cfg.n_sweeps, m,
                                      split=cfg.alpha > 1.0,
                                      row_wise=cfg.row_broadcast, ledger=ledger)
     ledger = ledger.charge(ledger_mod.icoa_sweep_cost(
-        tp, idx.shape[0], split=cfg.alpha > 1.0, row_wise=cfg.row_broadcast))
+        tp, m, split=cfg.alpha > 1.0, row_wise=cfg.row_broadcast))
     # taps are replicated D x D-side algebra (out_spec P() broadcasts them);
     # the static topology size keeps shapes un-traced
     taps0 = obs_taps.init_engine_taps(cfg.obs, tp.topology.n_agents,
                                       f_local.dtype)
 
     def eta_tilde_of(f_sub_all, diag_all):
-        a0 = _gathered_a0(f_sub_all, y[idx], diag_all, cfg.alpha, tp)
+        a0 = _gathered_a0(f_sub_all, take(y), diag_all, cfg.alpha, tp)
         if cfg.delta > 0.0:
             a = jax.lax.stop_gradient(minimax.robust_weights(
                 a0, cfg.delta, steps=cfg.minimax_steps, lr=cfg.minimax_lr))
@@ -136,7 +154,7 @@ def _sweep_body(cfg: ICOAConfig, tp, family, xcol, y, f_local, params_local,
         else:
             # paper-faithful schedule: every agent re-transmits its residual
             # before every update — O(N*D) wire bytes per update
-            f_sub_all = jax.lax.all_gather(f_local[0][idx], "agents")   # (D, N/alpha)
+            f_sub_all = jax.lax.all_gather(take(f_local[0]), "agents")  # (D, m)
             diag_all = jax.lax.all_gather(
                 jnp.mean((y - f_local[0]) ** 2), "agents")              # (D,) local variances
 
@@ -154,7 +172,7 @@ def _sweep_body(cfg: ICOAConfig, tp, family, xcol, y, f_local, params_local,
                 f_sub_all.at[i].set(f_sub_all[i] + step * g_unit), diag_all) > eta0
             return jnp.logical_and(~improved, probes < cfg.max_probes)
 
-        step0 = cfg.step0 * jnp.sqrt(jnp.asarray(idx.shape[0], jnp.float32))
+        step0 = cfg.step0 * jnp.sqrt(jnp.asarray(m, jnp.float32))
         step, probes = jax.lax.while_loop(
             cond, lambda s: (s[0] * cfg.backtrack, s[1] + 1),
             (step0, jnp.asarray(0, jnp.int32)))
@@ -162,7 +180,7 @@ def _sweep_body(cfg: ICOAConfig, tp, family, xcol, y, f_local, params_local,
 
         # scatter the gradient step back to full-length targets: only the
         # subsampled positions move (the paper re-fits on the perturbed vector)
-        f_hat_full = f_local[0].at[idx].add(step * g_unit)
+        f_hat_full = _add_at(f_local[0], idx, step * g_unit)
 
         # projection onto H_i — executed everywhere, kept only by agent i
         # (xcol is the agent's OWN columns: no attribute data moved)
@@ -171,7 +189,8 @@ def _sweep_body(cfg: ICOAConfig, tp, family, xcol, y, f_local, params_local,
         # accept/reject after projection (see core.icoa.sweep): agent i checks
         # its own post-projection objective on the shared subsample
         my_sub_new = jax.lax.psum(
-            jnp.where(me == i, new_f[idx], jnp.zeros_like(new_f[idx])), "agents")
+            jnp.where(me == i, take(new_f), jnp.zeros_like(take(new_f))),
+            "agents")
         eta_post = eta_tilde_of(f_sub_all.at[i].set(my_sub_new), diag_all)
         accept = eta_post > eta0
         tps = obs_taps.tap_accept(tps, cfg.obs, i, accept)
@@ -184,8 +203,9 @@ def _sweep_body(cfg: ICOAConfig, tp, family, xcol, y, f_local, params_local,
         f_local = jnp.where(is_me, new_f[None], f_local)
         if cfg.row_broadcast:
             # broadcast ONLY agent i's accepted row: one masked psum = O(N/alpha)
-            row = jax.lax.psum(jnp.where(is_me, new_f[idx], jnp.zeros_like(new_f[idx])),
-                               "agents")
+            row = jax.lax.psum(
+                jnp.where(is_me, take(new_f), jnp.zeros_like(take(new_f))),
+                "agents")
             dnew = jax.lax.psum(jnp.where(is_me, jnp.mean((y - new_f) ** 2), 0.0),
                                 "agents")
             f_cache = f_cache.at[i].set(row)
@@ -194,12 +214,12 @@ def _sweep_body(cfg: ICOAConfig, tp, family, xcol, y, f_local, params_local,
 
     # one initial gather (row_broadcast keeps it current; the paper-faithful
     # path re-gathers inside the loop and ignores the carry)
-    f_cache0 = jax.lax.all_gather(f_local[0][idx], "agents")
+    f_cache0 = jax.lax.all_gather(take(f_local[0]), "agents")
     diag_cache0 = jax.lax.all_gather(jnp.mean((y - f_local[0]) ** 2), "agents")
     if "codec_error" in taps0:
         # the dense schedule re-codes every probe; report the sweep-start
         # gather's round trip (what the incremental body's CovState absorbs)
-        sent0 = y[idx][None, :] - f_cache0
+        sent0 = take(y)[None, :] - f_cache0
         taps0 = obs_taps.tap_codec_error(taps0, cfg.obs, sent0,
                                          tp.relay_rows(sent0))
     f_local, params_local, f_cache, diag_cache, taps = jax.lax.fori_loop(
@@ -210,9 +230,9 @@ def _sweep_body(cfg: ICOAConfig, tp, family, xcol, y, f_local, params_local,
     if cfg.row_broadcast:
         f_sub_all, diag_all = f_cache, diag_cache
     else:
-        f_sub_all = jax.lax.all_gather(f_local[0][idx], "agents")
+        f_sub_all = jax.lax.all_gather(take(f_local[0]), "agents")
         diag_all = jax.lax.all_gather(jnp.mean((y - f_local[0]) ** 2), "agents")
-    a0 = _gathered_a0(f_sub_all, y[idx], diag_all, cfg.alpha, tp)
+    a0 = _gathered_a0(f_sub_all, take(y), diag_all, cfg.alpha, tp)
     if cfg.delta > 0.0:
         w = minimax.robust_weights(a0, cfg.delta, steps=cfg.minimax_steps, lr=cfg.minimax_lr)
     else:
@@ -250,12 +270,7 @@ def _sweep_body_incremental(cfg: ICOAConfig, tp, family, xcol, y, f_local,
     fl = tp.faults
     rnd = jnp.asarray(round_, jnp.int32)
 
-    if cfg.alpha > 1.0:
-        key, ksub = jax.random.split(key)
-        idx = cov.subsample_indices(ksub, n, cfg.alpha)   # same key everywhere
-    else:
-        idx = jnp.arange(n)
-    m = idx.shape[0]
+    idx, m, take = _subsample(cfg, key, n)
     split = cfg.alpha > 1.0          # Sec 4.1 exact-local-diagonal split
     protected = cfg.delta > 0.0
     uk = cfg.use_kernel
@@ -265,8 +280,8 @@ def _sweep_body_incremental(cfg: ICOAConfig, tp, family, xcol, y, f_local,
         retries=0 if fl is None else fl.max_retries)
 
     # the engine's ONLY full gather: residual rows + local variances, once
-    f_sub_all = jax.lax.all_gather(f_local[0][idx], "agents")       # (D, m)
-    sent0 = y[idx][None, :] - f_sub_all
+    f_sub_all = jax.lax.all_gather(take(f_local[0]), "agents")      # (D, m)
+    sent0 = take(y)[None, :] - f_sub_all
     r_sub0 = tp.relay_rows(sent0)
     if split:
         diag0 = tp.relay_scalars(
@@ -348,17 +363,18 @@ def _sweep_body_incremental(cfg: ICOAConfig, tp, family, xcol, y, f_local,
 
         # scatter the step to full-length targets; projection runs everywhere,
         # only the owner keeps it (no attribute data moved)
-        f_hat_full = f_local[0].at[idx].add(step * g_unit)
+        f_hat_full = _add_at(f_local[0], idx, step * g_unit)
         new_p = family.fit(jax.tree.map(lambda t: t[0], params_local),
                            xcol[0], f_hat_full)
         new_f = family.predict(new_p, xcol[0])
 
         # broadcast the CANDIDATE row + its variance: the per-update traffic
         cand_sub = jax.lax.psum(
-            jnp.where(me == i, new_f[idx], jnp.zeros_like(new_f[idx])), "agents")
+            jnp.where(me == i, take(new_f), jnp.zeros_like(take(new_f))),
+            "agents")
         cand_diag = tp.relay_scalar(jax.lax.psum(
             jnp.where(me == i, jnp.mean((y - new_f) ** 2), 0.0), "agents"), i)
-        r_cand = tp.relay_row(y[idx] - cand_sub, i)
+        r_cand = tp.relay_row(take(y) - cand_sub, i)
         if fl is not None:
             # wire-view corruption (see core.icoa._sweep_incremental): the
             # delivered row may arrive flipped; the owner's f stays clean

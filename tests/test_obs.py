@@ -543,7 +543,8 @@ def test_batch_fit_spans_cover_the_call_in_order(tmp_path):
     for call, parent in enumerate(parents):
         assert parent["parent"] is None
         assert parent["tags"] == {"n_trials": 3, "solver": "icoa",
-                                  "backend": "local", "agents_mesh": 1}
+                                  "backend": "local", "agents_mesh": 1,
+                                  "sub_rows": _N}
         kids = sorted((r for r in rows if r["parent"] == parent["id"]),
                       key=lambda r: r["id"])
         assert tuple(r["name"] for r in kids) == _BATCH_PHASES
@@ -566,8 +567,57 @@ def test_batch_fit_spans_cover_the_call_in_order(tmp_path):
         capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stdout + out.stderr
     table = out.stdout.split("== batch_fit calls ==")[1].splitlines()
-    assert table[2].split() == ["local", "1", "2", "6", table[2].split()[4],
-                                str(2 * wire_bytes)]
+    assert table[2].split() == ["local", "1", str(_N), "2", "6",
+                                table[2].split()[5], str(2 * wire_bytes)]
+
+
+_SUB_ROWS_SCRIPT = r"""
+import sys
+from repro import api, obs
+
+spec = api.ExperimentSpec(
+    data=api.DataSpec(n_train=120, n_test=120, seed=3),
+    agent=api.AgentSpec(family="polynomial", options=(("degree", 3),)),
+    solver=api.SolverSpec(n_sweeps=2, eps=0.0),
+    backend=api.BackendSpec(name="shard_map"))
+obs.configure(sys.argv[1])
+try:
+    for alpha in (1.0, 7.0):
+        api.batch_fit(api.replace(
+            spec, solver=api.replace(spec.solver, alpha=alpha)), 2)
+finally:
+    obs.disable()
+"""
+
+
+def test_batch_fit_sub_rows_tag_on_the_shard_map_backend(tmp_path):
+    """`sub_rows` is what each party sends per residual gather: all 120
+    instances at alpha 1 (the sweep bodies' identity path), the alpha
+    subsample above it; obs_report's batch table keys a row on it."""
+    from repro.core import covariance as cov
+
+    path = str(tmp_path / "mesh.jsonl")
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=5")
+    out = subprocess.run([sys.executable, "-c", _SUB_ROWS_SCRIPT, path],
+                         env=env, capture_output=True, text=True, timeout=900)
+    assert out.returncode == 0, out.stderr[-3000:]
+    rows = [json.loads(l) for l in open(path)]
+    calls = [r["tags"] for r in rows if r["name"] == "api.batch_fit"]
+    sub7 = cov.subsample_size(120, 7.0)
+    assert sub7 == 18
+    assert [(c["backend"], c["agents_mesh"], c["sub_rows"]) for c in calls] \
+        == [("shard_map", 5, 120), ("shard_map", 5, sub7)]
+
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "tools", "obs_report.py"), path],
+        capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stdout + out.stderr
+    table = out.stdout.split("== batch_fit calls ==")[1].splitlines()
+    assert table[1].split()[:3] == ["backend", "agents_mesh", "sub_rows"]
+    assert sorted(line.split()[:5] for line in table[2:4]) == [
+        ["shard_map", "5", "120", "1", "2"],
+        ["shard_map", "5", str(sub7), "1", "2"]]
 
 
 def test_serial_batch_fit_nests_its_api_fit_spans(tmp_path):

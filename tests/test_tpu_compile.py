@@ -13,6 +13,7 @@ these tests loads the TPU library, and every worker collects the same tests.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -122,11 +123,10 @@ def test_fit_runner_carries_kernels_for_v5e(one_chip, mosaic_kernels):
     assert "tpu_custom_call" in text
 
 
-def test_mesh_batch_program_carries_kernels_for_v5e_2x2(topo, mosaic_kernels):
+def _mesh_program_text(topo, alpha: float) -> str:
     """The shard_map backend's Monte-Carlo program for four parties of two
     columns on the four chips of a v5e:2x2, at 64K instances, with the fused
-    engine's kernels: XLA cannot partition a Mosaic kernel, so every kernel
-    must sit inside a shard_map (the record's Gram included)."""
+    engine's kernels, compiled for two trials."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
@@ -138,7 +138,7 @@ def test_mesh_batch_program_carries_kernels_for_v5e_2x2(topo, mosaic_kernels):
                           n_test=NP),
         agent=api.AgentSpec(family="polynomial", options=(("degree", 1),)),
         solver=api.SolverSpec(name="icoa", engine="fused", use_kernel=True,
-                              n_sweeps=10),
+                              n_sweeps=10, alpha=alpha),
         backend=api.BackendSpec(name="shard_map"))
     mesh = Mesh(np.array(topo.devices[:4]), ("agents",))
     run_fn = api.build_distributed_runner(spec, mesh=mesh)
@@ -149,5 +149,36 @@ def test_mesh_batch_program_carries_kernels_for_v5e_2x2(topo, mosaic_kernels):
 
     trials = jax.ShapeDtypeStruct((2,), jnp.int32,
                                   sharding=NamedSharding(mesh, PartitionSpec()))
-    text = _compiled_text(loop, (trials,))
+    return _compiled_text(loop, (trials,))
+
+
+def _shard_map_indexing(text: str) -> list:
+    """(result type, opcode) of each gather and scatter that the compiled
+    program runs inside the shard_map, told by its op_name metadata."""
+    out = []
+    for line in text.splitlines():
+        m = re.search(r"= (\w+\[[\d,]*\])\S* (gather|scatter)\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if m and name and "shard_map" in name.group(1):
+            out.append(m.groups())
+    return out
+
+
+def test_mesh_batch_program_carries_kernels_for_v5e_2x2(topo, mosaic_kernels):
+    """XLA cannot partition a Mosaic kernel, so every kernel must sit inside
+    a shard_map (the record's Gram included).  At alpha 1 the sweep body
+    moves the full-length residual rows as they are: no f32[N] gather or
+    scatter by an identity index is left inside the shard_map."""
+    text = _mesh_program_text(topo, alpha=1.0)
     assert "tpu_custom_call" in text and "all-gather" in text
+    indexing = _shard_map_indexing(text)
+    assert not [op for op in indexing if op[0] == f"f32[{NP}]"], indexing
+
+
+def test_mesh_batch_program_keeps_the_subsample_for_v5e_2x2(topo,
+                                                           mosaic_kernels):
+    """Minimax Protection's rate alpha > 1 still gathers its random N/alpha
+    subsample inside the shard_map and scatters the step back to f32[N]."""
+    indexing = _shard_map_indexing(_mesh_program_text(topo, alpha=4.0))
+    assert ("f32[16384]", "gather") in indexing, indexing
+    assert (f"f32[{NP}]", "scatter") in indexing, indexing

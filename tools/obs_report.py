@@ -9,8 +9,9 @@ Four sections, all derived from the `repro.obs.trace` schema
 
   spans    per-name count / total / mean / max wall seconds — where the run
            actually spent its host time (fit, resweep cadence, checkpoints)
-  batch    the `api.batch_fit` calls by backend and agent mesh size
-           (`agents_mesh`): calls, trials, mean wall seconds and the residual
+  batch    the `api.batch_fit` calls by backend, agent mesh size
+           (`agents_mesh`) and instances sent per residual gather
+           (`sub_rows`): calls, trials, mean wall seconds and the residual
            bytes the trials sent (`wire_bytes` of `batch_fit.assemble`)
   metrics  the per-record metric table from `stream.record` events (round,
            instance count, sweeps executed, eta, windowed train MSE,
@@ -70,14 +71,15 @@ def batch_table(rows: List[Dict[str, Any]]) -> List[str]:
     agg: Dict[tuple, List[tuple]] = defaultdict(list)
     for sid, r in calls.items():
         t = r.get("tags", {})
-        key = (t.get("backend", "-"), t.get("agents_mesh", "-"))
+        key = (t.get("backend", "-"), t.get("agents_mesh", "-"),
+               t.get("sub_rows", "-"))
         agg[key].append((t.get("n_trials", 0), float(r.get("dur_s", 0.0)),
                          wire.get(sid, 0)))
     out = ["== batch_fit calls ==",
-           f"{'backend':<10} {'agents_mesh':>11} {'calls':>6} {'trials':>8} "
-           f"{'mean_s':>10} {'wire_bytes':>14}"]
-    for (backend, mesh), cs in sorted(agg.items(), key=str):
-        out.append(f"{backend:<10} {mesh:>11} {len(cs):>6} "
+           f"{'backend':<10} {'agents_mesh':>11} {'sub_rows':>9} {'calls':>6} "
+           f"{'trials':>8} {'mean_s':>10} {'wire_bytes':>14}"]
+    for (backend, mesh, sub), cs in sorted(agg.items(), key=str):
+        out.append(f"{backend:<10} {mesh:>11} {sub:>9} {len(cs):>6} "
                    f"{sum(c[0] for c in cs):>8} "
                    f"{sum(c[1] for c in cs) / len(cs):>10.4f} "
                    f"{sum(c[2] for c in cs):>14}")
