@@ -5,13 +5,11 @@ Prints ``name,us_per_call,derived`` CSV rows. Usage:
     PYTHONPATH=src python -m benchmarks.run              # everything
     PYTHONPATH=src python -m benchmarks.run table1 fig5  # a subset
 
-Numbers destined for a checked-in BENCH_*.json should run under the pinned
-environment (allocator, host-device topology, persistent compilation cache):
-
-    PYTHONPATH=src tools/bench_env.sh python -m benchmarks.run sweep
-
-The harness prints a ``bench_env`` row recording which parts of that regime
-were active, so every CSV capture is self-describing.
+These suites reproduce the paper's numbers (MSE, bytes, fault counts) on the
+host.  Their ``us_per_call`` column is a host wall time, not a chip metric:
+the performance of record is the chip benchmark under ``bench/`` (PERF.md).
+The harness prints a ``host_env`` row recording the compile cache and
+``XLA_FLAGS`` it ran under, so every CSV capture is self-describing.
 """
 from __future__ import annotations
 
@@ -22,10 +20,9 @@ import traceback
 from repro.analysis import recompile
 from repro.launch.compile_cache import use_compile_cache
 
-from benchmarks import (batch_bench, comm_cost, faults_bench,
-                        fig1_overtraining, fig3_divergence, fig5_upper_bound,
-                        kernels_bench, roofline, serve_bench, sweep_engines,
-                        table1_algorithms, table2_minimax, transport_bench)
+from benchmarks import (comm_cost, faults_bench, fig1_overtraining,
+                        fig3_divergence, fig5_upper_bound, table1_algorithms,
+                        table2_minimax, transport_bench)
 
 SUITES = {
     "table1": table1_algorithms.run,     # paper Table 1
@@ -34,16 +31,8 @@ SUITES = {
     "table2": table2_minimax.run,        # paper Table 2
     "fig5": fig5_upper_bound.run,        # paper Fig. 5
     "comm": comm_cost.run,               # paper Fig. 2 / Sec 4 cost table
-    "kernels": kernels_bench.run,        # kernel micro-bench
-    "roofline": roofline.run,            # dry-run roofline table (Sec e/g)
-    "sweep": sweep_engines.run,          # dense vs incremental engine curve
-                                         # (writes BENCH_sweep.json)
-    "batch": batch_bench.run,            # Monte-Carlo trials/sec vs devices
-                                         # (writes BENCH_batch.json)
     "transport": transport_bench.run,    # trade-off curves per topology x
                                          # codec (writes BENCH_transport.json)
-    "serve": serve_bench.run,            # online ingest/resweep/predict
-                                         # latency (writes BENCH_serve.json)
     "faults": faults_bench.run,          # chaos harness: MSE + retry byte
                                          # overhead vs drop x topology x
                                          # policy (writes BENCH_faults.json)
@@ -51,11 +40,11 @@ SUITES = {
 
 
 def _env_row(cache: str) -> str:
-    """One self-describing row: which parts of tools/bench_env.sh are active."""
+    """One self-describing row: the allocator, compile cache and XLA flags."""
     alloc = "tcmalloc" if "tcmalloc" in os.environ.get("LD_PRELOAD", "") \
         else "glibc"
     xla = os.environ.get("XLA_FLAGS", "")
-    return f"bench_env,0,alloc={alloc};jax_cache={cache};xla_flags={xla or '-'}"
+    return f"host_env,0,alloc={alloc};jax_cache={cache};xla_flags={xla or '-'}"
 
 
 def main() -> int:
@@ -66,7 +55,7 @@ def main() -> int:
     # recompilation audit (DESIGN.md §9.3): active only when
     # REPRO_RECOMPILE_AUDIT names a JSON path — the audit is written at exit,
     # tagged per suite selection so tools/recompile_budget.json can hold one
-    # entry per benchmark entry point (bench_batch, bench_kernels, ...)
+    # entry per benchmark entry point (bench_faults, ...)
     recompile.install_from_env("bench_" + "_".join(sorted(which)))
     print("name,us_per_call,derived")
     print(_env_row(cache), flush=True)

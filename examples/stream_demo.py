@@ -50,7 +50,7 @@ def main():
     engine = PredictEngine(family, groups, n_attrs, spec.serve_buckets)
 
     # no ad-hoc stopwatches here: the engine's own obs.health rings/counters
-    # (the same ones serve_bench and the metrics_text scrape read) ARE the
+    # (the same ones the metrics_text scrape reads) ARE the
     # latency/throughput record — the request thread just drives traffic
     stop = threading.Event()
 

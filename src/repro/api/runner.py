@@ -261,8 +261,7 @@ def _local_batch_program(spec: ExperimentSpec, n_trials: int):
     Single device (or trial_devices=1): plain `vmap(run_fn)`.  Otherwise the
     vmapped batch is shard_map'd over the trial mesh, with padding/masking
     for n_trials % k != 0: the tail re-runs the last real trial (any index
-    is valid work) and callers slice its rows away.  Shared with
-    benchmarks/batch_bench.py so the timed program IS the production one.
+    is valid work) and callers slice its rows away.
     """
     run_fn = build_runner(spec)
     if spec.backend.checks == "raise":
@@ -298,7 +297,7 @@ def _shard_map_batch_program(spec: ExperimentSpec, n_trials: int):
     """The shard_map backend's pre-jit batch program: a per-device trial loop
     (lax.scan over the distributed run_fn) — each trial uses the whole agent
     mesh, so trials are sequential, but the loop is ONE XLA program, not k
-    eager fit() calls.  Shared with benchmarks/batch_bench.py."""
+    eager fit() calls."""
     run_fn = build_distributed_runner(spec)
 
     def loop(trials):

@@ -35,7 +35,7 @@ def gram(r: jnp.ndarray, use_kernel: bool = False) -> jnp.ndarray:
     if use_kernel:
         from repro.kernels.gram import ops as gram_ops
 
-        return (gram_ops.gram(r, use_pallas=True) / r.shape[1]).astype(r.dtype)
+        return (gram_ops.gram(r) / r.shape[1]).astype(r.dtype)
     # full f32 precision: the TPU default rounds through bf16 passes
     return jnp.matmul(r, r.T, precision=jax.lax.Precision.HIGHEST) / r.shape[1]
 

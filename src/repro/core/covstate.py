@@ -77,7 +77,7 @@ def row_product(vec: jnp.ndarray, r_sub: jnp.ndarray,
     if use_kernel:
         from repro.kernels.gram import ops as gram_ops
 
-        return gram_ops.row_gram(vec, r_sub, use_pallas=True).astype(r_sub.dtype)
+        return gram_ops.row_gram(vec, r_sub).astype(r_sub.dtype)
     return jnp.matmul(r_sub, vec, precision=_HIGHEST)
 
 

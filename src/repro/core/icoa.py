@@ -638,7 +638,7 @@ def _sweep_fused(family, cfg: ICOAConfig, tp, params: Any, f: jnp.ndarray,
         if idx is None:
             if uk:
                 etas, cross, _, gnorm = sweep_ops.probe_sweep(
-                    rs, m_inv, s, eta, i, steps, use_pallas=True)
+                    rs, m_inv, s, eta, i, steps)
                 g_unit = ((2.0 / m) * s[i] / gnorm) * cross
             else:
                 g = gradient.cached_row_gradient(s, rs, i)
@@ -710,7 +710,7 @@ def _sweep_fused(family, cfg: ICOAConfig, tp, params: Any, f: jnp.ndarray,
         if uk:
             m_inv, s, u_eff, accept, _ = sweep_ops.commit_sweep(
                 rs, m_inv, s, eta, i, delta, diag_keep, diag_add, threshold,
-                can_tx, use_pallas=True)
+                can_tx)
         else:
             m_inv, s, u_eff, accept, _ = sweep_ref.commit_sweep_ref(
                 rs, m_inv, s, eta, i, delta, diag_keep, diag_add, threshold,

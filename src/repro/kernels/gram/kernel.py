@@ -13,13 +13,12 @@ VMEM budget at the default BN=2048, Dp=128: tile 128*2048*4 = 1 MiB + scratch
 64 KiB — comfortably inside the ~16 MiB/core VMEM.  Dp=1024 at BN=2048 does
 not fit a v5e's VMEM; the main path stays at D<=512 until D is tiled.
 
-The `*_batched` variants prepend a batch grid axis (grid = (B, NK), batch
-outermost, N-blocks innermost-sequential) so a whole Monte-Carlo trial batch
-runs as ONE kernel launch: each batch step re-initialises the VMEM accumulator
-at its first N-block and flushes at its last, reusing the same scratch across
-batch elements. They back the custom-vmap rules in ops.py — `jax.vmap` over
-the public `gram`/`row_gram` lowers to these instead of failing to batch
-`pallas_call`.
+Each kernel is batch-gridded, grid (B, NK) with the batch outermost and the
+N-blocks innermost-sequential, so a whole Monte-Carlo trial batch runs as
+ONE launch: each batch step re-initialises the VMEM accumulator at its first
+N-block and flushes at its last, reusing the same scratch across batch
+elements.  A single trial runs at B=1; kernels.runtime holds the layout and
+the batching rule the ops in ops.py use.
 """
 from __future__ import annotations
 
@@ -30,8 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["gram_pallas", "gram_pallas_batched", "row_gram_pallas",
-           "row_gram_pallas_batched"]
+__all__ = ["gram_pallas", "row_gram_pallas"]
 
 # full-precision f32 dots split their operands in VMEM; at Dp=512 and
 # block_n=2048 that passes the default 16 MiB scoped limit (a v5e core has
@@ -45,38 +43,6 @@ def _dot(x, y):
     return jax.lax.dot_general(x, y, (((1,), (1,)), ((), ())),
                                precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
-
-
-def _gram_kernel(r_ref, out_ref, acc_ref, *, nk: int):
-    k = pl.program_id(0)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    blk = r_ref[...].astype(jnp.float32)        # (Dp, BN)
-    acc_ref[...] += _dot(blk, blk)               # R_blk @ R_blk^T
-
-    @pl.when(k == nk - 1)
-    def _flush():
-        out_ref[...] = acc_ref[...]
-
-
-def gram_pallas(r: jnp.ndarray, *, block_n: int = 2048, interpret: bool = True) -> jnp.ndarray:
-    """r: (Dp, Np), Np a multiple of block_n. Returns fp32 (Dp, Dp)."""
-    dp, np_ = r.shape
-    assert np_ % block_n == 0, (np_, block_n)
-    nk = np_ // block_n
-    return pl.pallas_call(
-        functools.partial(_gram_kernel, nk=nk),
-        grid=(nk,),
-        in_specs=[pl.BlockSpec((dp, block_n), lambda k: (0, k))],
-        out_specs=pl.BlockSpec((dp, dp), lambda k: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((dp, dp), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((dp, dp), jnp.float32)],
-        compiler_params=_VMEM,
-        interpret=interpret,
-    )(r)
 
 
 def _gram_batch_kernel(r_ref, out_ref, acc_ref, *, nk: int):
@@ -94,13 +60,13 @@ def _gram_batch_kernel(r_ref, out_ref, acc_ref, *, nk: int):
         out_ref[0] = acc_ref[...]
 
 
-def gram_pallas_batched(r: jnp.ndarray, *, block_n: int = 2048,
-                        interpret: bool = True) -> jnp.ndarray:
-    """r: (B, Dp, Np) -> fp32 (B, Dp, Dp): one launch for the whole batch.
+def gram_pallas(r: jnp.ndarray, *, block_n: int = 2048,
+                interpret: bool = True) -> jnp.ndarray:
+    """r: (B, Dp, Np), Np a multiple of block_n -> fp32 (B, Dp, Dp).
 
-    Grid (B, NK) with the N axis innermost: the accumulator scratch carries
-    within one batch element and is re-zeroed at each element's first N-block,
-    so the batch axis needs no extra VMEM beyond the single-trial kernel.
+    The accumulator scratch carries within one batch element and is
+    re-zeroed at each element's first N-block, so the batch axis needs no
+    VMEM beyond one element's.
     """
     b, dp, np_ = r.shape
     assert np_ % block_n == 0, (np_, block_n)
@@ -115,50 +81,6 @@ def gram_pallas_batched(r: jnp.ndarray, *, block_n: int = 2048,
         compiler_params=_VMEM,
         interpret=interpret,
     )(r)
-
-
-def _row_gram_kernel(r_ref, v_ref, out_ref, acc_ref, *, nk: int):
-    k = pl.program_id(0)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    blk = r_ref[...].astype(jnp.float32)         # (Dp, BN)
-    vec = v_ref[...].astype(jnp.float32)         # (8, BN); row 0 is the payload
-    acc_ref[...] += _dot(blk, vec)               # R_blk @ v_blk^T -> (Dp, 8)
-
-    @pl.when(k == nk - 1)
-    def _flush():
-        out_ref[...] = acc_ref[...]
-
-
-def row_gram_pallas(r: jnp.ndarray, v: jnp.ndarray, *, block_n: int = 2048,
-                    interpret: bool = True) -> jnp.ndarray:
-    """Fused row-Gram r_i @ R^T: the one unavoidable O(N*D) product of the
-    incremental covariance engine's rank-2 row update (DESIGN.md §5).
-
-    r: (Dp, Np), v: (8, Np) with the probe row in v[0] and zero padding below
-    (8 = fp32 sublane width); Np a multiple of block_n. Returns fp32 (Dp, 8)
-    whose column 0 is R @ v[0]. Same blocked N-grid + VMEM fp32 accumulator
-    as `gram_pallas`; the (Dp, BN) x (BN, 8) product rides the MXU with the
-    vector broadcast across sublanes.
-    """
-    dp, np_ = r.shape
-    assert np_ % block_n == 0, (np_, block_n)
-    assert v.shape == (8, np_), (v.shape, np_)
-    nk = np_ // block_n
-    return pl.pallas_call(
-        functools.partial(_row_gram_kernel, nk=nk),
-        grid=(nk,),
-        in_specs=[pl.BlockSpec((dp, block_n), lambda k: (0, k)),
-                  pl.BlockSpec((8, block_n), lambda k: (0, k))],
-        out_specs=pl.BlockSpec((dp, 8), lambda k: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((dp, 8), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((dp, 8), jnp.float32)],
-        compiler_params=_VMEM,
-        interpret=interpret,
-    )(r, v)
 
 
 def _row_gram_batch_kernel(r_ref, v_ref, out_ref, acc_ref, *, nk: int):
@@ -177,12 +99,17 @@ def _row_gram_batch_kernel(r_ref, v_ref, out_ref, acc_ref, *, nk: int):
         out_ref[0] = acc_ref[...]
 
 
-def row_gram_pallas_batched(r: jnp.ndarray, v: jnp.ndarray, *,
-                            block_n: int = 2048,
-                            interpret: bool = True) -> jnp.ndarray:
-    """r: (B, Dp, Np), v: (B, 8, Np) -> fp32 (B, Dp, 8): batched `row_gram_pallas`
-    with the same (batch-outer, N-inner) grid/accumulator discipline as
-    `gram_pallas_batched`."""
+def row_gram_pallas(r: jnp.ndarray, v: jnp.ndarray, *, block_n: int = 2048,
+                    interpret: bool = True) -> jnp.ndarray:
+    """Fused row-Gram r_i @ R^T: the one unavoidable O(N*D) product of the
+    incremental covariance engine's rank-2 row update (DESIGN.md §5).
+
+    r: (B, Dp, Np), v: (B, 8, Np) with the probe row in v[:, 0] and zero
+    padding below (8 = fp32 sublane width); Np a multiple of block_n.
+    Returns fp32 (B, Dp, 8) whose column 0 is R @ v[0].  Same grid and
+    accumulator discipline as `gram_pallas`; the (Dp, BN) x (BN, 8) product
+    rides the MXU with the vector broadcast across sublanes.
+    """
     b, dp, np_ = r.shape
     assert np_ % block_n == 0, (np_, block_n)
     assert v.shape == (b, 8, np_), (v.shape, r.shape)

@@ -24,20 +24,19 @@ the same N-grid, then applies the whole `covstate._smw_pieces` algebra
 with accept folded into the coefficients (rejection multiplies the update by
 zero — an exact no-op, matching the reference bit for bit in fp32).
 
-Scalar plumbing: TPU Pallas wants >= 2D operands, so D-vectors travel as
-(Dp, 8) column packs (payload in column 0, zeros elsewhere), N-vectors as
-(8, Np) row packs (payload in row 0 — same as gram's row_gram), and scalars
-as an (8, 128) parameter plate read back via iota masks.  The zero padding
-is load-bearing: it makes full-array reductions equal payload reductions.
+Operands follow the packing contract of kernels.runtime: D-vectors as
+(Dp, 8) column packs, N-vectors as (8, Np) row packs, scalars on an (8, 128)
+parameter plate read back here via iota masks, all zero-padded.
 
 VMEM at BN=2048, Dp=128: R tile 1 MiB + m_inv 64 KiB + packs/accumulators
 ~12 KiB — the D=100/N=2000 benchmark case is a single resident tile.  Both
 kernels compile for a TPU v5e up to Dp=512 at BN=2048; at Dp=1024 they run
 out of VMEM, so D above 512 needs tiling over D.
 
-The `*_batched` variants prepend a batch grid axis (batch outermost,
-N-blocks innermost-sequential, accumulators re-initialised per element) and
-back the custom-vmap rules in ops.py, exactly like kernels.gram.
+Both kernels are batch-gridded like kernels.gram's: every operand has a
+leading B axis, grid (B, NK) with the batch outermost and the N-blocks
+innermost-sequential, accumulators re-initialised per element.  A single
+agent update runs at B=1.
 
 No in-kernel determinant sanitisation: the checkify rail lives in the ref
 oracle (kernels.sweep.ref) that validates this kernel.
@@ -51,8 +50,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["probe_sweep_pallas", "probe_sweep_pallas_batched",
-           "commit_sweep_pallas", "commit_sweep_pallas_batched"]
+__all__ = ["probe_sweep_pallas", "commit_sweep_pallas"]
 
 _F32 = jnp.float32
 
@@ -89,8 +87,8 @@ def _col0_entry(colpack, i_f):
 
 
 def _probe_finalize(minv, s_col, pars, steps, acc_p, acc_gg):
-    """Last-block epilogue shared by the single and batched probe kernels:
-    arrays in, (etas, p, stats) out — the caller owns the output writes."""
+    """Last-block epilogue of the probe kernel: arrays in, (etas, p, stats)
+    out — the caller owns the output writes."""
     i_f = _plate_scalar(pars, 0)
     m = _plate_scalar(pars, 1)
     eta = _plate_scalar(pars, 2)
@@ -122,68 +120,6 @@ def _probe_finalize(minv, s_col, pars, steps, acc_p, acc_gg):
     return etas, p_col, stats
 
 
-def _probe_kernel(r_ref, minv_ref, s_ref, pars_ref, steps_ref,
-                  etas_ref, cross_ref, p_ref, stats_ref,
-                  acc_p, acc_gg, *, nk: int):
-    k = pl.program_id(0)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_p[...] = jnp.zeros_like(acc_p)
-        acc_gg[...] = jnp.zeros_like(acc_gg)
-
-    blk = r_ref[...].astype(_F32)                    # (Dp, BN)
-    s_col = s_ref[...].astype(_F32)                  # (Dp, 8)
-    cross_blk = _dot(s_col, blk, ((0,), (0,)))       # (8, BN); row 0 = s @ R_blk
-    cross_ref[...] = cross_blk
-    acc_p[...] += _dot(blk, cross_blk, ((1,), (1,)))  # (Dp, 8) += R_blk @ cross^T
-    acc_gg[...] += jnp.sum(cross_blk * cross_blk)    # broadcast: every entry
-
-    @pl.when(k == nk - 1)
-    def _flush():
-        etas, p_col, stats = _probe_finalize(
-            minv_ref[...].astype(_F32), s_col, pars_ref[...].astype(_F32),
-            steps_ref[...].astype(_F32), acc_p[...], acc_gg[...])
-        etas_ref[...] = etas
-        p_ref[...] = p_col
-        stats_ref[...] = stats
-
-
-def probe_sweep_pallas(r: jnp.ndarray, m_inv: jnp.ndarray, s: jnp.ndarray,
-                       pars: jnp.ndarray, steps: jnp.ndarray, *,
-                       block_n: int = 2048, interpret: bool = True):
-    """r: (Dp, Np), m_inv: (Dp, Dp), s: (Dp, 8), pars/steps: (8, 128) with
-    pars[0, :3] = (i, m, eta) and steps[0] the zero-padded schedule.
-    Returns fp32 (etas (8, 128), cross (8, Np), p (Dp, 8), stats (8, 128))
-    with stats[0, :2] = (gnorm, scale)."""
-    dp, np_ = r.shape
-    assert np_ % block_n == 0, (np_, block_n)
-    assert m_inv.shape == (dp, dp) and s.shape == (dp, 8), (m_inv.shape, s.shape)
-    assert pars.shape == (8, 128) and steps.shape == (8, 128)
-    nk = np_ // block_n
-    return pl.pallas_call(
-        functools.partial(_probe_kernel, nk=nk),
-        grid=(nk,),
-        in_specs=[pl.BlockSpec((dp, block_n), lambda k: (0, k)),
-                  pl.BlockSpec((dp, dp), lambda k: (0, 0)),
-                  pl.BlockSpec((dp, 8), lambda k: (0, 0)),
-                  pl.BlockSpec((8, 128), lambda k: (0, 0)),
-                  pl.BlockSpec((8, 128), lambda k: (0, 0))],
-        out_specs=[pl.BlockSpec((8, 128), lambda k: (0, 0)),
-                   pl.BlockSpec((8, block_n), lambda k: (0, k)),
-                   pl.BlockSpec((dp, 8), lambda k: (0, 0)),
-                   pl.BlockSpec((8, 128), lambda k: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((8, 128), _F32),
-                   jax.ShapeDtypeStruct((8, np_), _F32),
-                   jax.ShapeDtypeStruct((dp, 8), _F32),
-                   jax.ShapeDtypeStruct((8, 128), _F32)],
-        scratch_shapes=[pltpu.VMEM((dp, 8), _F32),
-                        pltpu.VMEM((8, 128), _F32)],
-        compiler_params=_VMEM,
-        interpret=interpret,
-    )(r, m_inv, s, pars, steps)
-
-
 def _probe_batch_kernel(r_ref, minv_ref, s_ref, pars_ref, steps_ref,
                         etas_ref, cross_ref, p_ref, stats_ref,
                         acc_p, acc_gg, *, nk: int):
@@ -211,10 +147,13 @@ def _probe_batch_kernel(r_ref, minv_ref, s_ref, pars_ref, steps_ref,
         stats_ref[0] = stats
 
 
-def probe_sweep_pallas_batched(r, m_inv, s, pars, steps, *,
-                               block_n: int = 2048, interpret: bool = True):
-    """Batched `probe_sweep_pallas`: every operand gains a leading B axis;
-    grid (B, NK), batch outermost, accumulators re-initialised per element."""
+def probe_sweep_pallas(r, m_inv, s, pars, steps, *, block_n: int = 2048,
+                       interpret: bool = True):
+    """r: (B, Dp, Np), m_inv: (B, Dp, Dp), s: (B, Dp, 8), pars/steps:
+    (B, 8, 128) with pars[:, 0, :3] = (i, m, eta) and steps[:, 0] the
+    zero-padded schedule; Np a multiple of block_n.  Returns fp32 (etas
+    (B, 8, 128), cross (B, 8, Np), p (B, Dp, 8), stats (B, 8, 128)) with
+    stats[:, 0, :2] = (gnorm, scale)."""
     b, dp, np_ = r.shape
     assert np_ % block_n == 0, (np_, block_n)
     nk = np_ // block_n
@@ -242,8 +181,8 @@ def probe_sweep_pallas_batched(r, m_inv, s, pars, steps, *,
 
 
 def _commit_finalize(minv, s_col, pars, acc_w, acc_dd):
-    """Last-block epilogue shared by the single and batched commit kernels:
-    arrays in, (m_inv', s', u_eff, stats) out — the caller owns the writes."""
+    """Last-block epilogue of the commit kernel: arrays in, (m_inv', s',
+    u_eff, stats) out — the caller owns the writes."""
     i_f = _plate_scalar(pars, 0)
     m = _plate_scalar(pars, 1)
     eta = _plate_scalar(pars, 2)
@@ -287,69 +226,6 @@ def _commit_finalize(minv, s_col, pars, acc_w, acc_dd):
     return minv_new, s_new, acc * u, stats
 
 
-def _commit_kernel(r_ref, delta_ref, minv_ref, s_ref, pars_ref,
-                   minv_out, s_out, u_out, stats_ref,
-                   acc_w, acc_dd, *, nk: int):
-    k = pl.program_id(0)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_w[...] = jnp.zeros_like(acc_w)
-        acc_dd[...] = jnp.zeros_like(acc_dd)
-
-    blk = r_ref[...].astype(_F32)                    # (Dp, BN)
-    dblk = delta_ref[...].astype(_F32)               # (8, BN); row 0 payload
-    acc_w[...] += _dot(blk, dblk, ((1,), (1,)))      # (Dp, 8) += R_blk @ d^T
-    acc_dd[...] += jnp.sum(dblk * dblk)
-
-    @pl.when(k == nk - 1)
-    def _flush():
-        minv_new, s_new, u_eff, stats = _commit_finalize(
-            minv_ref[...].astype(_F32), s_ref[...].astype(_F32),
-            pars_ref[...].astype(_F32), acc_w[...], acc_dd[...])
-        minv_out[...] = minv_new
-        s_out[...] = s_new
-        u_out[...] = u_eff
-        stats_ref[...] = stats
-
-
-def commit_sweep_pallas(r: jnp.ndarray, delta: jnp.ndarray,
-                        m_inv: jnp.ndarray, s: jnp.ndarray,
-                        pars: jnp.ndarray, *, block_n: int = 2048,
-                        interpret: bool = True):
-    """r: (Dp, Np), delta: (8, Np), m_inv: (Dp, Dp), s: (Dp, 8), pars (8, 128)
-    with pars[0, :7] = (i, m, eta, diag_keep, diag_add, threshold, can_tx).
-    Returns fp32 (m_inv' (Dp, Dp), s' (Dp, 8), u_eff (Dp, 8), stats (8, 128))
-    with stats[0, :2] = (obj_post, accept)."""
-    dp, np_ = r.shape
-    assert np_ % block_n == 0, (np_, block_n)
-    assert delta.shape == (8, np_), (delta.shape, np_)
-    assert m_inv.shape == (dp, dp) and s.shape == (dp, 8)
-    assert pars.shape == (8, 128)
-    nk = np_ // block_n
-    return pl.pallas_call(
-        functools.partial(_commit_kernel, nk=nk),
-        grid=(nk,),
-        in_specs=[pl.BlockSpec((dp, block_n), lambda k: (0, k)),
-                  pl.BlockSpec((8, block_n), lambda k: (0, k)),
-                  pl.BlockSpec((dp, dp), lambda k: (0, 0)),
-                  pl.BlockSpec((dp, 8), lambda k: (0, 0)),
-                  pl.BlockSpec((8, 128), lambda k: (0, 0))],
-        out_specs=[pl.BlockSpec((dp, dp), lambda k: (0, 0)),
-                   pl.BlockSpec((dp, 8), lambda k: (0, 0)),
-                   pl.BlockSpec((dp, 8), lambda k: (0, 0)),
-                   pl.BlockSpec((8, 128), lambda k: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((dp, dp), _F32),
-                   jax.ShapeDtypeStruct((dp, 8), _F32),
-                   jax.ShapeDtypeStruct((dp, 8), _F32),
-                   jax.ShapeDtypeStruct((8, 128), _F32)],
-        scratch_shapes=[pltpu.VMEM((dp, 8), _F32),
-                        pltpu.VMEM((8, 128), _F32)],
-        compiler_params=_VMEM,
-        interpret=interpret,
-    )(r, delta, m_inv, s, pars)
-
-
 def _commit_batch_kernel(r_ref, delta_ref, minv_ref, s_ref, pars_ref,
                          minv_out, s_out, u_out, stats_ref,
                          acc_w, acc_dd, *, nk: int):
@@ -376,10 +252,13 @@ def _commit_batch_kernel(r_ref, delta_ref, minv_ref, s_ref, pars_ref,
         stats_ref[0] = stats
 
 
-def commit_sweep_pallas_batched(r, delta, m_inv, s, pars, *,
-                                block_n: int = 2048, interpret: bool = True):
-    """Batched `commit_sweep_pallas`: leading B axis on every operand;
-    grid (B, NK), batch outermost, accumulators re-initialised per element."""
+def commit_sweep_pallas(r, delta, m_inv, s, pars, *, block_n: int = 2048,
+                        interpret: bool = True):
+    """r: (B, Dp, Np), delta: (B, 8, Np), m_inv: (B, Dp, Dp), s: (B, Dp, 8),
+    pars: (B, 8, 128) with pars[:, 0, :7] = (i, m, eta, diag_keep, diag_add,
+    threshold, can_tx).  Returns fp32 (m_inv' (B, Dp, Dp), s' (B, Dp, 8),
+    u_eff (B, Dp, 8), stats (B, 8, 128)) with stats[:, 0, :2] = (obj_post,
+    accept)."""
     b, dp, np_ = r.shape
     assert np_ % block_n == 0, (np_, block_n)
     nk = np_ // block_n

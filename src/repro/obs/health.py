@@ -8,7 +8,7 @@ that races a write sees at worst one stale sample, never a torn structure);
 `Counter` is a monotone event counter with a first/last timestamp pair, so
 throughput is derived from observed wall time instead of a caller's own
 stopwatch arithmetic (one source of truth — examples/stream_demo.py and
-benchmarks/serve_bench.py both read these).
+the metrics_text hook both read these).
 
 `prometheus_text` renders a metric list in the Prometheus text exposition
 format (v0.0.4) — the `stream.serve.metrics_text` hook builds its payload

@@ -55,7 +55,7 @@ class PredictEngine:
         # in-engine runtime health (repro.obs.health): ONE latency ring per
         # bucket program, fed by the engine itself — pad + execute +
         # block_until_ready, the full request-visible cost of that program.
-        # Consumers (serve_bench, stream_demo, the metrics_text hook) read
+        # Consumers (stream_demo, the metrics_text hook) read
         # these instead of running their own stopwatches.
         self.latency = {b: obs_health.LatencyRing() for b in self.buckets}
         self.requests = obs_health.Counter()
@@ -118,7 +118,7 @@ class PredictEngine:
 
         B <= max bucket: one padded call.  Larger B strides through the
         largest bucket.  Either way every executed program was compiled at
-        warmup — zero steady-state retraces (audit-gated in serve_bench).
+        warmup — zero steady-state retraces (tests/test_stream.py pins it).
         Per-bucket execution latency lands in `self.latency` (obs.health
         rings); `predict` blocks on the result so the observed time is the
         caller's, not the dispatch queue's.

@@ -1,5 +1,5 @@
 """Device-parallel Monte Carlo (PR 4): trial-axis sharding, batched Pallas
-Gram kernels, the shard_map compiled trial loop, converged-sweep reporting,
+kernels, the shard_map compiled trial loop, converged-sweep reporting,
 and the BackendSpec execution knobs."""
 import inspect
 import os
@@ -15,6 +15,7 @@ import pytest
 from repro import api
 from repro.core import minimax
 from repro.kernels.gram import gram, row_gram
+from repro.kernels.sweep import commit_sweep, probe_sweep
 from repro.launch.mesh import make_trial_mesh
 
 _N = 160
@@ -37,25 +38,60 @@ _F32 = dict(rtol=1e-4, atol=1e-4)    # fp32 kernel accumulation vs f32 einsum
 
 def test_gram_batches_under_vmap():
     r = jax.random.normal(jax.random.PRNGKey(0), (4, 5, 300))
-    got = jax.jit(jax.vmap(lambda x: gram(x, use_pallas=True)))(r)
+    got = jax.jit(jax.vmap(gram))(r)
     np.testing.assert_allclose(got, jnp.einsum("bdn,ben->bde", r, r), **_F32)
 
 
 def test_row_gram_batches_under_vmap_including_mixed_batching():
     r = jax.random.normal(jax.random.PRNGKey(1), (4, 5, 300))
     v = jax.random.normal(jax.random.PRNGKey(2), (4, 300))
-    got = jax.vmap(lambda vv, rr: row_gram(vv, rr, use_pallas=True))(v, r)
+    got = jax.vmap(row_gram)(v, r)
     np.testing.assert_allclose(got, jnp.einsum("bdn,bn->bd", r, v), **_F32)
     # r batched, v shared: the rule broadcasts the unbatched operand
-    got2 = jax.vmap(lambda rr: row_gram(v[0], rr, use_pallas=True))(r)
+    got2 = jax.vmap(lambda rr: row_gram(v[0], rr))(r)
     np.testing.assert_allclose(got2, jnp.einsum("bdn,n->bd", r, v[0]), **_F32)
 
 
 def test_gram_nested_vmap_flattens():
     r = jax.random.normal(jax.random.PRNGKey(3), (2, 3, 5, 300))
-    got = jax.vmap(jax.vmap(lambda x: gram(x, use_pallas=True)))(r)
+    got = jax.vmap(jax.vmap(gram))(r)
     np.testing.assert_allclose(got, jnp.einsum("abdn,aben->abde", r, r),
                                **_F32)
+
+
+def _kernel_op(name):
+    """One main-path kernel op over (r (D, N), v (N,)), every other operand
+    shared: a multi-block grid (block_n=128 over 300 instances)."""
+    d = 6
+    m = jax.random.normal(jax.random.PRNGKey(4), (d, 2 * d))
+    m_inv = m @ m.T / (2 * d) + jnp.eye(d)
+    s = jnp.sum(m_inv, axis=1)
+    eta = jnp.sum(s)
+    steps = 0.5 ** jnp.arange(1, 5, dtype=jnp.float32)
+    return d, {
+        "gram": lambda r, v: gram(r, block_n=128),
+        "row_gram": lambda r, v: row_gram(v, r, block_n=128),
+        "probe_sweep": lambda r, v: probe_sweep(r, m_inv, s, eta, 2, steps,
+                                                block_n=128),
+        "commit_sweep": lambda r, v: commit_sweep(
+            r, m_inv, s, eta, 2, 0.05 * v, 1.0, 0.0, -jnp.inf, 1.0,
+            block_n=128),
+    }[name]
+
+
+@pytest.mark.parametrize("name", ["gram", "row_gram", "probe_sweep",
+                                  "commit_sweep"])
+def test_unbatched_kernel_call_is_row_zero_of_the_batch(name):
+    """An unbatched op call runs the batch-gridded kernel at B=1, so it
+    equals row 0 of the vmapped call bit for bit."""
+    d, op = _kernel_op(name)
+    r = jax.random.normal(jax.random.PRNGKey(5), (3, d, 300))
+    v = jax.random.normal(jax.random.PRNGKey(6), (3, 300))
+    single = jax.tree.leaves(op(r[0], v[0]))
+    batched = jax.tree.leaves(jax.vmap(op)(r, v))
+    assert len(single) == len(batched)
+    for one, many in zip(single, batched):
+        np.testing.assert_array_equal(np.asarray(one), np.asarray(many[0]))
 
 
 def test_use_kernel_spec_compiles_in_batch_fit():

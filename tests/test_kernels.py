@@ -27,7 +27,7 @@ def _tol(dt):
        block=st.sampled_from([128, 256]))
 def test_gram_matches_ref(d, n, dt, block):
     r = (jax.random.normal(jax.random.PRNGKey(d * 1000 + n), (d, n))).astype(dt)
-    out = gram(r, use_pallas=True, block_n=block)
+    out = gram(r, block_n=block)
     ref = gram_ref(r)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-3 if dt == jnp.float32 else 2e-2,
@@ -37,7 +37,7 @@ def test_gram_matches_ref(d, n, dt, block):
 def test_gram_paper_shape():
     """The paper's D=5, N=4000 configuration."""
     r = jax.random.normal(jax.random.PRNGKey(0), (5, 4000))
-    np.testing.assert_allclose(np.asarray(gram(r, use_pallas=True)),
+    np.testing.assert_allclose(np.asarray(gram(r)),
                                np.asarray(gram_ref(r)), rtol=1e-4, atol=1e-2)
 
 
@@ -51,7 +51,7 @@ def test_gram_paper_shape():
 def test_row_gram_matches_ref(d, n, dt, block):
     r = (jax.random.normal(jax.random.PRNGKey(d * 991 + n), (d, n))).astype(dt)
     v = (jax.random.normal(jax.random.PRNGKey(n * 7 + d), (n,))).astype(dt)
-    out = row_gram(v, r, use_pallas=True, block_n=block)
+    out = row_gram(v, r, block_n=block)
     ref = row_gram_ref(v, r)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-3 if dt == jnp.float32 else 2e-2,
@@ -63,7 +63,7 @@ def test_row_gram_is_one_gram_row():
     the incremental engine's rank-2 update is built on."""
     r = jax.random.normal(jax.random.PRNGKey(1), (7, 2048))
     full = gram_ref(r)
-    np.testing.assert_allclose(np.asarray(row_gram(r[3], r, use_pallas=True)),
+    np.testing.assert_allclose(np.asarray(row_gram(r[3], r)),
                                np.asarray(full[3]), rtol=1e-4, atol=1e-2)
 
 

@@ -726,4 +726,4 @@ def test_bench_schema_check_passes_on_the_checked_in_benchmarks():
          "check", REPO],
         capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stdout + out.stderr
-    assert "BENCH_serve.json" in out.stdout
+    assert "BENCH_transport.json" in out.stdout

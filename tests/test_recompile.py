@@ -92,7 +92,7 @@ def test_absorb_counts_during_active_scope_neither_drops_nor_doubles(
     x6 = jnp.ones((6,), jnp.float32)
     with recompile.count_compilations() as local:
         jax.jit(lambda x: x + 5.0)(x6)              # in-process compile
-        # a forked worker reports back mid-scope (batch_bench's protocol)
+        # a forked worker reports back mid-scope (absorb_counts' protocol)
         recompile.absorb_counts({"worker_sweep": 4})
         recompile.absorb_counts({"worker_sweep": 1, "worker_predict": 2})
     # absorbed counts land on the installed audit log, accumulated not
@@ -131,7 +131,7 @@ def test_checked_in_budget_covers_the_audited_entries():
         os.path.join(REPO, "tools", "recompile_budget.json"))
     # the two processes CI audits must have declared ceilings
     assert "tier1_suite" in budget
-    assert "bench_batch" in budget
+    assert "bench_faults" in budget
     for entry, spec in budget.items():
         assert int(spec["max_compiles"]) > 0, entry
 

@@ -283,6 +283,32 @@ def test_ingest_no_steady_state_retrace():
     assert log.total == 0, log.counts
 
 
+def test_ingest_with_every_tap_on_no_steady_state_retrace():
+    """Taps add carry state to the compiled resweep; with all of them on
+    the steady state still compiles nothing."""
+    from repro.obs import ALL_TAPS, ObsSpec
+
+    exp = api.ExperimentSpec(
+        data=api.DataSpec(source="cosine", n_train=256, n_test=64),
+        solver=api.SolverSpec(name="icoa", n_sweeps=5, eps=0.0),
+        obs=ObsSpec(taps=tuple(ALL_TAPS)))
+    spec = _stream_spec(experiment=exp, window=128, chunk=64,
+                        total_instances=256, resweep_every=128)
+    ing = build_ingestor(spec)
+    src = ChunkSource("cosine", 64, 64)
+    state = ing.init_state()
+    for t in range(4):                     # warm: ingest + both resweep fills
+        state = ing.ingest(state, *src(t))
+        if (t + 1) % 2 == 0:
+            state, _ = ing.resweep(state)
+    with recompile.count_compilations() as log:
+        for t in range(4, 8):
+            state = ing.ingest(state, *src(t))
+            if (t + 1) % 2 == 0:
+                state, _ = ing.resweep(state)
+    assert log.total == 0, log.counts
+
+
 # ------------------------------------------------------------ spec layer
 
 

@@ -37,8 +37,7 @@ def test_probe_kernel_matches_ref(d, n, k, block):
     r, m_inv, s, eta, _ = _scene(d, n, seed=d * 1000 + n)
     steps = 0.7 ** jnp.arange(1, k + 1, dtype=jnp.float32)
     i = d // 2
-    out = probe_sweep(r, m_inv, s, eta, i, steps, use_pallas=True,
-                      block_n=block)
+    out = probe_sweep(r, m_inv, s, eta, i, steps, block_n=block)
     ref = probe_sweep_ref(r, m_inv, s, eta, i, steps)
     for got, want, name in zip(out, ref, ("etas", "cross", "p", "gnorm")):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -47,13 +46,13 @@ def test_probe_kernel_matches_ref(d, n, k, block):
 
 
 def test_probe_kernel_paper_shape_exact_schedule():
-    """D=100/N=2000 (the BENCH_sweep headline shape): the closed-form
+    """D=100/N=2000 (the paper-scale sweep shape): the closed-form
     schedule computed in-core must match the oracle essentially exactly —
     both evaluate the same fp32 closed form off the same accumulated
     scalars."""
     r, m_inv, s, eta, _ = _scene(100, 2000, seed=7)
     steps = 0.5 ** jnp.arange(1, 9, dtype=jnp.float32)
-    out = probe_sweep(r, m_inv, s, eta, 13, steps, use_pallas=True)
+    out = probe_sweep(r, m_inv, s, eta, 13, steps)
     ref = probe_sweep_ref(r, m_inv, s, eta, 13, steps)
     np.testing.assert_allclose(np.asarray(out[0]), np.asarray(ref[0]),
                                rtol=1e-5, atol=1e-5)
@@ -65,7 +64,7 @@ def test_probe_vmap_routes_to_batched_kernel():
     r0, m_inv, s, eta, _ = _scene(d, n, seed=0)
     steps = 0.6 ** jnp.arange(1, k + 1, dtype=jnp.float32)
     def fn(r):
-        return probe_sweep(r, m_inv, s, eta, 2, steps, use_pallas=True)
+        return probe_sweep(r, m_inv, s, eta, 2, steps)
     batched = jax.vmap(fn)(rs)
     for j in range(b):
         single = fn(rs[j])
@@ -90,7 +89,7 @@ def test_commit_kernel_matches_ref(d, n, block, accept, gated):
     can_tx = jnp.asarray(0.0 if gated else 1.0, r.dtype)
     args = (r, m_inv, s, eta, i, delta, jnp.asarray(1.0, r.dtype),
             jnp.asarray(0.0, r.dtype), threshold, can_tx)
-    out = commit_sweep(*args, use_pallas=True, block_n=block)
+    out = commit_sweep(*args, block_n=block)
     ref = commit_sweep_ref(*args)
     names = ("m_inv", "s", "u_eff", "accept", "obj_post")
     for got, want, name in zip(out, ref, names):
@@ -105,7 +104,7 @@ def test_commit_reject_is_exact_noop():
     on x - 0.0 == x so a rejected probe can't drift the carried state."""
     r, m_inv, s, eta, delta = _scene(17, 400, seed=3)
     out = commit_sweep(r, m_inv, s, eta, 4, delta, 1.0, 0.0,
-                       jnp.asarray(jnp.inf, r.dtype), 1.0, use_pallas=True)
+                       jnp.asarray(jnp.inf, r.dtype), 1.0)
     assert not bool(out[3])
     np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(m_inv))
     np.testing.assert_array_equal(np.asarray(out[1]), np.asarray(s))
@@ -117,8 +116,7 @@ def test_commit_vmap_routes_to_batched_kernel():
     deltas = jnp.stack([_scene(d, n, seed=s_)[4] for s_ in range(b)])
     def fn(dl):
         return commit_sweep(r, m_inv, s, eta, 5, dl, 1.0, 0.0,
-                            jnp.asarray(-jnp.inf, r.dtype), 1.0,
-                            use_pallas=True)
+                            jnp.asarray(-jnp.inf, r.dtype), 1.0)
     batched = jax.vmap(fn)(deltas)
     for j in range(b):
         single = fn(deltas[j])
@@ -137,12 +135,12 @@ def test_kernels_on_padding_boundaries(d, n):
     (zero padding is load-bearing: full-array reductions == payload)."""
     r, m_inv, s, eta, delta = _scene(d, n, seed=d + n)
     steps = jnp.asarray([0.5, 0.25], jnp.float32)
-    out = probe_sweep(r, m_inv, s, eta, 0, steps, use_pallas=True)
+    out = probe_sweep(r, m_inv, s, eta, 0, steps)
     ref = probe_sweep_ref(r, m_inv, s, eta, 0, steps)
     np.testing.assert_allclose(np.asarray(out[0]), np.asarray(ref[0]),
                                rtol=2e-4, atol=2e-4)
     out = commit_sweep(r, m_inv, s, eta, 0, delta, 1.0, 0.0,
-                       jnp.asarray(-jnp.inf, r.dtype), 1.0, use_pallas=True)
+                       jnp.asarray(-jnp.inf, r.dtype), 1.0)
     ref = commit_sweep_ref(r, m_inv, s, eta, 0, delta, 1.0, 0.0,
                            jnp.asarray(-jnp.inf, r.dtype), 1.0)
     np.testing.assert_allclose(np.asarray(out[0]), np.asarray(ref[0]),

@@ -54,12 +54,10 @@ def one_chip(topo):
 def mosaic_kernels(monkeypatch):
     """This process's backend is the CPU, so the ops would pick the
     interpreter; force the compiled kernels the TPU backend would pick."""
-    from repro.kernels.gram import ops as gram_ops
-    from repro.kernels.sweep import ops as sweep_ops
+    from repro.kernels import runtime
 
-    for ops in (gram_ops, sweep_ops):
-        monkeypatch.setattr(ops, "resolve_interpret",
-                            lambda explicit=None: False)
+    monkeypatch.setattr(runtime, "resolve_interpret",
+                        lambda explicit=None: False)
 
 
 def _shapes(sharding, *shapes):
@@ -71,25 +69,19 @@ def _compiled_text(fn, args) -> str:
 
 
 def _kernel_cases(dp: int):
-    """(kernel, operand shapes) for every main-path kernel at width dp."""
+    """(kernel, operand shapes) for every main-path kernel at width dp, at
+    B=1 (an unbatched op call, as in the shard_map backend) and at B=8 (a
+    vmapped trial batch)."""
     r, m, col, plate = (dp, NP), (dp, dp), (dp, 8), (8, 128)
     row = (8, NP)
-
-    def b(*shapes):
-        return tuple((BATCH,) + s for s in shapes)
-
-    return {
+    ops = {
         "gram": (gram_k.gram_pallas, (r,)),
-        "gram_batched": (gram_k.gram_pallas_batched, b(r)),
         "row_gram": (gram_k.row_gram_pallas, (r, row)),
-        "row_gram_batched": (gram_k.row_gram_pallas_batched, b(r, row)),
         "probe": (sweep_k.probe_sweep_pallas, (r, m, col, plate, plate)),
-        "probe_batched": (sweep_k.probe_sweep_pallas_batched,
-                          b(r, m, col, plate, plate)),
         "commit": (sweep_k.commit_sweep_pallas, (r, row, m, col, plate)),
-        "commit_batched": (sweep_k.commit_sweep_pallas_batched,
-                           b(r, row, m, col, plate)),
     }
+    return {f"{name}-b{b}": (kernel, tuple((b,) + s for s in shapes))
+            for name, (kernel, shapes) in ops.items() for b in (1, BATCH)}
 
 
 _CASES = [(name, dp) for dp in (128, 512) for name in _kernel_cases(dp)]
