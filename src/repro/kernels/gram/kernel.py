@@ -4,14 +4,15 @@ This is the paper's per-sweep compute hot-spot (eq. 14): D agent residual
 vectors of N instances each, N >> D. TPU mapping:
 
   * grid over N-blocks; each step loads one (Dp, BN) tile of R into VMEM
-    (Dp = D padded to the 128 MXU lane width by the wrapper, BN a multiple of
-    128) and issues a (Dp, BN) x (BN, Dp) MXU matmul;
+    (Dp = D padded to the 8-row fp32 sublane multiple by the wrapper, BN a
+    multiple of 128) and issues a (Dp, BN) x (BN, Dp) MXU matmul;
   * a (Dp, Dp) fp32 VMEM scratch accumulates across grid steps (the N axis is
     the sequential innermost grid dim), written out on the last step.
 
-VMEM budget at the default BN=2048, Dp=128: tile 128*2048*4 = 1 MiB + scratch
-64 KiB — comfortably inside the ~16 MiB/core VMEM.  Dp=1024 at BN=2048 does
-not fit a v5e's VMEM; the main path stays at D<=512 until D is tiled.
+VMEM budget at the default BN=2048: the tile is Dp*2048*4 bytes, 64 KiB at
+Dp=8 (D<=8) and 1 MiB at Dp=128, plus a (Dp, Dp) scratch — comfortably
+inside the ~16 MiB/core VMEM.  Dp=1024 at BN=2048 does not fit a v5e's
+VMEM; the main path stays at D<=512 until D is tiled.
 
 Each kernel is batch-gridded, grid (B, NK) with the batch outermost and the
 N-blocks innermost-sequential, so a whole Monte-Carlo trial batch runs as

@@ -8,14 +8,16 @@ kernel.  The answer depends on process-global state only (the backend), so
 it is safe as a jit static argument or an lru_cache key.
 
 Layout (gram, row_gram, probe, commit).  The residual matrix R (D, N) is
-zero-padded to (Dp, Np): D to the 128-lane width, N to a multiple of the
-N-block ``bn`` (at most ``block_n``, at least one lane width).  TPU Pallas
-wants >= 2-D operands, so D-vectors travel as (Dp, 8) column packs (payload
-in column 0), N-vectors as (8, Np) row packs (payload in row 0; 8 is the
-fp32 sublane width) and scalars on an (8, 128) parameter plate (payload
-along row 0).  The zero padding is load-bearing: it makes full-array
-reductions equal payload reductions.  Outputs are fp32 (the accumulation
-dtype); the ops slice the payload back out.
+zero-padded to (Dp, Np): D to a multiple of the 8-row fp32 sublane width,
+N to a multiple of the N-block ``bn`` (at most ``block_n``, at least one
+128-lane width).  D lies on sublanes in every (Dp, .) operand and each
+block spans the whole D axis, so any multiple of 8 tiles, and a wider pad
+would only stream zero rows.  TPU Pallas wants >= 2-D operands, so
+D-vectors travel as (Dp, 8) column packs (payload in column 0), N-vectors
+as (8, Np) row packs (payload in row 0) and scalars on an (8, 128)
+parameter plate (payload along row 0).  The zero padding is load-bearing:
+it makes full-array reductions equal payload reductions.  Outputs are fp32
+(the accumulation dtype); the ops slice the payload back out.
 
 Batching rule.  Each op has ONE batch-gridded Pallas kernel, grid (B, NK):
 the batch axis outermost, the N-blocks innermost and sequential.  An
@@ -39,6 +41,7 @@ __all__ = ["resolve_interpret", "pad_geometry", "pad2", "row_pack",
            "col_pack", "plate", "batch_gridded"]
 
 _LANE = 128
+_SUBLANE = 8
 
 
 def resolve_interpret(explicit: Optional[bool] = None) -> bool:
@@ -55,7 +58,7 @@ def _pad_to(x: int, m: int) -> int:
 def pad_geometry(d: int, n: int, block_n: int):
     """(Dp, Np, bn) for a (d, n) residual matrix and a requested N-block."""
     bn = min(block_n, _pad_to(n, _LANE))
-    return _pad_to(d, _LANE), _pad_to(n, bn), bn
+    return _pad_to(d, _SUBLANE), _pad_to(n, bn), bn
 
 
 def pad2(x: jnp.ndarray, rows: int, cols: int) -> jnp.ndarray:

@@ -28,10 +28,11 @@ Operands follow the packing contract of kernels.runtime: D-vectors as
 (Dp, 8) column packs, N-vectors as (8, Np) row packs, scalars on an (8, 128)
 parameter plate read back here via iota masks, all zero-padded.
 
-VMEM at BN=2048, Dp=128: R tile 1 MiB + m_inv 64 KiB + packs/accumulators
-~12 KiB — the D=100/N=2000 benchmark case is a single resident tile.  Both
-kernels compile for a TPU v5e up to Dp=512 at BN=2048; at Dp=1024 they run
-out of VMEM, so D above 512 needs tiling over D.
+VMEM at BN=2048: R tile Dp*2048*4 bytes (64 KiB at Dp=8, 832 KiB at the
+D=100 fit's Dp=104) + m_inv Dp*Dp*4 + packs/accumulators — a D=100/N=2000
+sweep is a single resident tile.  Both kernels compile for a TPU v5e up to
+Dp=512 at BN=2048; at Dp=1024 they run out of VMEM, so D above 512 needs
+tiling over D.
 
 Both kernels are batch-gridded like kernels.gram's: every operand has a
 leading B axis, grid (B, NK) with the batch outermost and the N-blocks

@@ -10,6 +10,10 @@ import numpy as np
 import pytest
 from hypcompat import given, settings, st
 
+from repro.core import covstate
+from repro.kernels.gram.ops import row_gram
+from repro.kernels.gram.ref import row_gram_ref
+from repro.kernels.runtime import pad_geometry
 from repro.kernels.sweep.ops import commit_sweep, probe_sweep
 from repro.kernels.sweep.ref import commit_sweep_ref, probe_sweep_ref
 
@@ -129,10 +133,66 @@ def test_commit_vmap_routes_to_batched_kernel():
 # --------------------------------------------------- packing edge geometry
 
 
-@pytest.mark.parametrize("d,n", [(1, 7), (128, 128), (129, 2049), (3, 4096)])
+@pytest.mark.parametrize("d,dp", [(1, 8), (4, 8), (5, 8), (8, 8), (9, 16),
+                                  (100, 104), (128, 128), (129, 136),
+                                  (512, 512)])
+def test_pad_geometry_rounds_parties_to_the_sublane_multiple(d, dp):
+    """The party axis pads to the smallest multiple of 8 (the fp32 sublane
+    width) that holds D; the instance axis keeps its lane blocking."""
+    assert pad_geometry(d, 2000, 2048) == (dp, 2048, 2048)
+
+
+@pytest.mark.parametrize("n,block_n,np_,bn", [
+    (7, 2048, 128, 128), (2000, 2048, 2048, 2048), (2049, 2048, 4096, 2048),
+    (300, 256, 512, 256), (65536, 2048, 65536, 2048)])
+def test_pad_geometry_keeps_the_instance_blocking(n, block_n, np_, bn):
+    """N pads to a multiple of the N-block, which is the requested block cut
+    to N's lane multiple; D does not enter it."""
+    for d in (5, 100):
+        assert pad_geometry(d, n, block_n)[1:] == (np_, bn)
+
+
+@pytest.mark.parametrize("d,n", [(5, 2000), (4, 8192)])
+def test_benchmark_widths_run_on_eight_rows(d, n):
+    """The benchmark's party counts (D=5 at N=2000, D=4 at a multi-block N)
+    reach the kernels as 8-row operands, and probe, commit and row_gram
+    there agree with their oracles and with covstate's jnp row product."""
+    r, m_inv, s, eta, delta = _scene(d, n, seed=d * 31 + n)
+    np_ = pad_geometry(d, n, 2048)[1]
+    steps = 0.5 ** jnp.arange(1, 9, dtype=jnp.float32)
+    text = jax.jit(probe_sweep).lower(r, m_inv, s, eta, 1, steps).as_text()
+    assert f"tensor<8x{np_}xf32>" in text
+    assert f"tensor<128x{np_}xf32>" not in text
+    tol = dict(rtol=2e-4, atol=2e-4 * n ** 0.5)
+    out = probe_sweep(r, m_inv, s, eta, 1, steps)
+    ref = probe_sweep_ref(r, m_inv, s, eta, 1, steps)
+    for got, want, name in zip(out, ref, ("etas", "cross", "p", "gnorm")):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   err_msg=name, **tol)
+    args = (r, m_inv, s, eta, d - 1, delta, jnp.asarray(1.0, r.dtype),
+            jnp.asarray(0.0, r.dtype), jnp.asarray(-jnp.inf, r.dtype),
+            jnp.asarray(1.0, r.dtype))
+    out = commit_sweep(*args)
+    ref = commit_sweep_ref(*args)
+    names = ("m_inv", "s", "u_eff", "accept", "obj_post")
+    for got, want, name in zip(out, ref, names):
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   err_msg=name, **tol)
+    got = np.asarray(row_gram(delta, r))
+    for want in (row_gram_ref(delta, r), covstate.row_product(delta, r)):
+        np.testing.assert_allclose(got, np.asarray(want), rtol=1e-3,
+                                   atol=1e-2 * n ** 0.5)
+    np.testing.assert_array_equal(
+        np.asarray(covstate.row_product(delta, r, use_kernel=True)), got)
+
+
+@pytest.mark.parametrize("d,n", [(1, 7), (8, 2048), (9, 2000), (128, 128),
+                                 (129, 2049), (3, 4096)])
 def test_kernels_on_padding_boundaries(d, n):
-    """Exact lane multiples, one-over, and tiny shapes all pad correctly
-    (zero padding is load-bearing: full-array reductions == payload)."""
+    """Exact sublane and lane multiples, one-over, and tiny shapes all pad
+    correctly (zero padding is load-bearing: full-array reductions ==
+    payload)."""
     r, m_inv, s, eta, delta = _scene(d, n, seed=d + n)
     steps = jnp.asarray([0.5, 0.25], jnp.float32)
     out = probe_sweep(r, m_inv, s, eta, 0, steps)

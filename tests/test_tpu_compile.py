@@ -3,9 +3,9 @@
 Interpret mode cannot see what Mosaic refuses (a float iota, a slice that
 breaks the tiling, more VMEM than a kernel may use), so every kernel the
 ICOA main path launches is compiled here for a described v5e chip that is
-not attached, at Dp=128 and Dp=512 (the widest the VMEM plan at
-block_n=2048 holds) with Np=65536.  Nothing runs; only the TPU compiler
-checks.
+not attached, with Np=65536, at Dp=8 (the sublane multiple every D<=8
+pads to), Dp=104 (D=100), Dp=128 and Dp=512 (the widest the VMEM plan at
+block_n=2048 holds).  Nothing runs; only the TPU compiler checks.
 
 The topology is described inside a module fixture: only the worker that runs
 these tests loads the TPU library, and every worker collects the same tests.
@@ -84,7 +84,8 @@ def _kernel_cases(dp: int):
             for name, (kernel, shapes) in ops.items() for b in (1, BATCH)}
 
 
-_CASES = [(name, dp) for dp in (128, 512) for name in _kernel_cases(dp)]
+_CASES = [(name, dp) for dp in (8, 104, 128, 512)
+          for name in _kernel_cases(dp)]
 
 
 @pytest.mark.parametrize("name,dp", _CASES,
