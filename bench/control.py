@@ -78,7 +78,8 @@ def _frozen_trials():
 
 def _control_high():
     """The control in the program's place: every record that the check
-    reads comes from the reference at "high" instead."""
+    reads comes from the configuration's own reference at "high" instead
+    (`BatchDriver.reference`, the module the configuration names)."""
     from bench import drivers
 
     real = drivers.BatchDriver._call
@@ -177,7 +178,8 @@ def write_limits(workload: str, summary: dict, names) -> None:
     """The limit of each named number, from the program's largest reading
     (lower) and the least upper reading: the control's smallest where that
     is three times the lower or more, a fault's smallest where that is ten
-    times the lower (three for a sweep that changes nothing)."""
+    times the lower (three for a sweep that changes nothing).  The file
+    also lists the faults that some limit catches on every seed."""
     numbers = {}
     for k in names:
         s = summary[k]
@@ -194,9 +196,14 @@ def write_limits(workload: str, summary: dict, names) -> None:
         numbers[k] = {"limit": float("%.3g" % limit), "lower": lower,
                       "upper": upper,
                       "upper_from": min(uppers, key=uppers.get)}
+    # the faults that read above some limit on every seed they ran:
+    # bench/tests/test_faults.py plants each at the rehearsal size
+    faults = sorted({f for k, lim in numbers.items()
+                     for f, v in summary[k]["fault_min"].items()
+                     if v > lim["limit"]})
     path = os.path.join(run.BENCH, "limits", workload + ".json")
     with open(path, "w") as f:
-        json.dump({"numbers": numbers}, f, indent=1)
+        json.dump({"numbers": numbers, "faults": faults}, f, indent=1)
     print(f"control: wrote {path}: {json.dumps(numbers)}", file=sys.stderr)
 
 

@@ -2,8 +2,19 @@
 (today `batch_fit`).  Each driver builds the program's specs from the
 configuration, warms every shape the window will use, measures one
 closed-loop window, and afterwards checks what the window produced against
-bench.reference.  `_call` returns the histories the check reads, and
-`reference` the reference's records for them.
+the configuration's reference.  `_call` returns the histories the check
+reads, and `reference` the reference's records for them.
+
+A configuration names its reference by the key "reference": a module under
+bench/ (default `reference`, the unprotected ICOA of bench/reference.py)
+with a function `records(data, seeds, *, agent, solver, n_sweeps, prec)`
+that returns each trial's records 0..n_sweeps as (train, test, eta), each
+of shape (trials, n_sweeps + 1).  `data` is every trial's (xcols, y,
+xcols_test, y_test) along a leading trial axis, regenerated here from the
+trial's seed; `seeds` are those seeds, which are also the trials' solver
+seeds; `agent` and `solver` are the configuration's blocks; `prec` is
+"highest" for the reference and "high" for its control.  A deployment
+whose mathematics differ brings its own module, and no file here changes.
 
 Seeds: a run's base seed is drawn from `--seed` into [0, 2e9), so that the
 program's int32 seeds (base + trial index, and + 1) never overflow and
@@ -13,6 +24,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import importlib
 import sys
 import time
 from typing import Any, Dict, List
@@ -21,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import data, reference
+from bench import data
 
 SEED_SPAN = 2_000_000_000
 
@@ -84,7 +96,6 @@ class _Driver:
         self.base = int(np.random.SeedSequence(seed).generate_state(1)[0]
                         % SEED_SPAN)
         self.groups = groups_of(self.cfg)
-        self.degree = self.cfg["agent"]["degree"]
 
     def reference_data(self, seed: int):
         d = self.cfg["data"]
@@ -180,13 +191,16 @@ class BatchDriver(_Driver):
         return record_gaps(list(zip(hists, self.reference())))
 
     def reference(self, precision: str = "highest"):
-        """Records 0..check_sweeps of every trial, trial t from the data of
-        seed base + t, as one vmapped reference program."""
+        """Records 0..check_sweeps of every trial, trial t from the data and
+        the solver seed base + t, from the configuration's reference."""
         seeds = self.base + jnp.arange(self.trials)
         dat = jax.vmap(self.reference_data)(seeds)
-        k = self.traffic["check_sweeps"]
+        ref = importlib.import_module(
+            "bench." + self.cfg.get("reference", "reference"))
         with jax.default_matmul_precision(precision):
-            out = jax.vmap(lambda *a: reference.fit_records(
-                *a, degree=self.degree, n_sweeps=k, prec=precision))(*dat)
+            out = ref.records(dat, seeds, agent=self.cfg["agent"],
+                              solver=self.cfg["solver"],
+                              n_sweeps=self.traffic["check_sweeps"],
+                              prec=precision)
         out = [np.asarray(v) for v in out]
         return [[v[t] for v in out] for t in range(self.trials)]

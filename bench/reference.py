@@ -154,3 +154,18 @@ def fit_records(xcols, y, xcols_test, y_test, degree: int, n_sweeps: int,
         params, f = sweep(params, f, xcols, y, degree, prec)
         out.append(record(params, f, y, xcols_test, y_test, degree, prec))
     return tuple(jnp.stack(v) for v in zip(*out))
+
+
+def records(data, seeds, *, agent: dict, solver: dict, n_sweeps: int,
+            prec: str = "highest"):
+    """Records 0..n_sweeps of every trial from `fit_records`, vmapped over
+    the leading trial axis of `data` (the interface bench/drivers.py
+    calls).  This ICOA draws nothing, so the seeds go unused; it sends
+    every residual and weights the ensemble in closed form, so the
+    configuration's solver has to run alpha 1 and delta 0."""
+    del seeds
+    if float(solver["alpha"]) != 1.0 or float(solver["delta"]) != 0.0:
+        raise ValueError("bench/reference.py is ICOA at alpha 1 and delta 0; "
+                         "name another reference in the configuration")
+    return jax.vmap(lambda *a: fit_records(
+        *a, degree=agent["degree"], n_sweeps=n_sweeps, prec=prec))(*data)

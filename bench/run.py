@@ -4,10 +4,13 @@
 
 The cell is looked up by name in BENCHMARK.json; its configuration
 (bench/configs), traffic mix (bench/traffic), per-layer readers
-(bench/metrics) and limits (bench/limits) are files found by name, so a cell
-is added with new files and entries only.  One run sets up and warms every
-shape the cell uses (`setup_s`), measures for `--seconds` (with `--trace 1`
-for the traffic's `trace_seconds` at most, under the profiler), then frees
+(bench/metrics), limits (bench/limits) and reference (the module under
+bench/ that the configuration's "reference" names, bench/reference.py by
+default; see bench/drivers.py) are files found by name, so a cell is added
+with new files and entries only, a deployment with mathematics of its own
+included.  One run sets up and warms every shape the cell uses
+(`setup_s`), measures for `--seconds` (with `--trace 1` for the traffic's
+`trace_seconds` at most, under the profiler), then frees
 the program's state, runs the reference on what the window produced and
 prints one JSON line.  Without a TPU holding the cell's chips it exits 3
 and prints no result; `--cpu-rehearsal` runs the tiny sizes of the

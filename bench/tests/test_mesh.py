@@ -1,9 +1,10 @@
 """Checks of the four-chip agent mesh cell `batch.corrlin-p4.mesh`: its two
 per-layer readers (`collective_exposed.mesh`, `ici_roofline.mesh`) on
-hand-made four-device traces, and whole runs at the configuration's
+hand-made four-device traces, and a whole run at the configuration's
 rehearsal size on 4 forced CPU devices, where a planted frozen mesh sweep
 (`distributed._sweep_shmap` returning its state unchanged) must turn
-`correct` false and the untouched program must keep it true.
+`correct` false (the untouched program's run is in test_faults.py, as
+every cell's with a limits file).
 
     JAX_PLATFORMS=cpu python3 -m pytest -q bench/tests
 """
@@ -126,29 +127,28 @@ def test_cell_lists_both_readers():
 
 
 # A whole rehearsal run of the mesh cell in a subprocess with 4 forced host
-# devices; FROZEN plants a mesh sweep that returns its state unchanged.
+# devices, with a mesh sweep planted that returns its state unchanged.
 _SCRIPT = r"""
 import contextlib, io, json, sys
 sys.path.insert(0, ROOT)
 sys.path.insert(0, ROOT + "/src")
 from bench import run
-if FROZEN:
-    from repro.api import runner
-    from repro.core import distributed
+from repro.api import runner
+from repro.core import distributed
 
-    real = distributed._sweep_shmap
+real = distributed._sweep_shmap
 
-    def frozen(mesh, cfg, family):
-        sweep = real(mesh, cfg, family)
+def frozen(mesh, cfg, family):
+    sweep = real(mesh, cfg, family)
 
-        def same(xcols, y, f, params, key, ledger, round_=None):
-            _, _, w, ledger, taps = sweep(xcols, y, f, params, key, ledger,
-                                          round_)
-            return f, params, w, ledger, taps
-        return same
+    def same(xcols, y, f, params, key, ledger, round_=None):
+        _, _, w, ledger, taps = sweep(xcols, y, f, params, key, ledger,
+                                      round_)
+        return f, params, w, ledger, taps
+    return same
 
-    distributed._sweep_shmap = frozen
-    runner.clear_program_cache()
+distributed._sweep_shmap = frozen
+runner.clear_program_cache()
 out = io.StringIO()
 with contextlib.redirect_stdout(out):
     rc = run.main(["--workload", MESH, "--seed", "2147483659",
@@ -158,26 +158,17 @@ print("RESULT " + out.getvalue().strip().splitlines()[-1])
 """
 
 
-def _rehearse(frozen: bool) -> dict:
+def test_frozen_mesh_sweep_turns_correct_false():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["JAX_PLATFORMS"] = "cpu"
-    script = (_SCRIPT.replace("ROOT", repr(run.ROOT))
-              .replace("FROZEN", repr(frozen)).replace("MESH", repr(MESH)))
+    script = _SCRIPT.replace("ROOT", repr(run.ROOT)).replace("MESH",
+                                                            repr(MESH))
     out = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
     line = [ln for ln in out.stdout.splitlines() if ln.startswith("RESULT ")]
-    return json.loads(line[-1][len("RESULT "):])
-
-
-def test_frozen_mesh_sweep_turns_correct_false():
-    result = _rehearse(frozen=True)
+    result = json.loads(line[-1][len("RESULT "):])
     over = [k for k, c in result["compared"].items()
             if not (c["value"] is not None and c["value"] <= c["limit"])]
     assert result["compared"] and over and not result["correct"]
-
-
-def test_sound_mesh_run_is_correct():
-    result = _rehearse(frozen=False)
-    assert result["compared"] and result["correct"]
